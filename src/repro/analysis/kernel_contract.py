@@ -13,8 +13,10 @@ kernel-registry     every module-level function in ``kernels/`` that
                     issues a ``pl.pallas_call`` must be registered in
                     ``KERNEL_CONTRACT`` (the declared output dtypes).
 kernel-arity        kernel function parameter count must equal
-                    ``len(in_specs) + len(out_specs)`` (refs are passed
-                    inputs-then-outputs).
+                    ``num_scalar_prefetch + len(in_specs) + len(out_specs)
+                    + len(scratch_shapes)`` (refs are passed in that
+                    order; a ``grid_spec=`` call's keywords count as the
+                    ``pallas_call``'s own).
 kernel-index-map    each ``BlockSpec`` index lambda takes exactly one
                     argument per grid dimension and returns one index per
                     block dimension.
@@ -60,13 +62,13 @@ KERNEL_CONTRACT: Dict[str, Tuple[Optional[str], ...]] = {
     "ungroup_bf16_2d": ("uint16",),
     "bytegroup_fp32_2d": ("uint8", "uint8", "uint8", "uint8"),
     "ungroup_fp32_2d": ("uint32",),
-    "histogram_2d": ("int32",),
     "chunk_histogram_2d": ("int32",),
     "xor_elems_2d": (None,),
     "xor_delta_2d": ("uint32", "int32"),
-    "bitpack_encode_chunks": ("uint32", "int32"),
-    "bitpack_encode_chunks_multi": ("uint32", "int32"),
-    "huffdecode_chunks_multi": ("uint8", "int32"),
+    # The scalar-unit kernels move 32-bit words only (SMEM holds nothing
+    # narrower); their wrappers reinterpret the words as uint32 / bytes.
+    "bitpack_encode_chunks_multi": ("int32", "int32"),
+    "huffdecode_chunks_multi": ("int32", "int32"),
     "plane_consumer": (None,),
 }
 
@@ -319,6 +321,23 @@ def _check_call(
 ) -> List[Violation]:
     out: List[Violation] = []
     kw = {k.arg: k.value for k in call.keywords if k.arg is not None}
+    grid_spec = kw.get("grid_spec")
+    if isinstance(grid_spec, ast.Call):
+        kw.update(
+            {k.arg: k.value for k in grid_spec.keywords if k.arg is not None}
+        )
+    n_prefetch = kw.get("num_scalar_prefetch", ast.Constant(0))
+    n_prefetch = (
+        n_prefetch.value
+        if isinstance(n_prefetch, ast.Constant) and isinstance(n_prefetch.value, int)
+        else None
+    )
+    scratch = kw.get("scratch_shapes")
+    n_scratch = (
+        0 if scratch is None
+        else len(scratch.elts) if isinstance(scratch, (ast.List, ast.Tuple))
+        else None
+    )
     grid = kw.get("grid")
     grid_dims: Optional[List[ast.AST]] = (
         list(grid.elts) if isinstance(grid, ast.Tuple) else None
@@ -357,8 +376,14 @@ def _check_call(
 
     # --- kernel arity -----------------------------------------------------
     n_out = out_specs.count if out_specs.count is not None else n_shapes
-    if in_specs.count is not None and n_out is not None and call.args:
-        expected = in_specs.count + n_out
+    if (
+        in_specs.count is not None
+        and n_out is not None
+        and n_prefetch is not None
+        and n_scratch is not None
+        and call.args
+    ):
+        expected = n_prefetch + in_specs.count + n_out + n_scratch
         for fn in _resolve_kernel_fns(call.args[0], sf, wrapper):
             n_params = len(
                 fn.args.posonlyargs + fn.args.args + fn.args.kwonlyargs
@@ -370,8 +395,9 @@ def _check_call(
                         sf.rel,
                         call.lineno,
                         f"kernel {fn.name}() takes {n_params} refs but "
-                        f"this pallas_call passes {in_specs.count} inputs "
-                        f"+ {n_out} outputs",
+                        f"this pallas_call passes {n_prefetch} scalar "
+                        f"prefetch + {in_specs.count} inputs + {n_out} "
+                        f"outputs + {n_scratch} scratch",
                     )
                 )
 
@@ -407,7 +433,8 @@ def _check_call(
                 a.arg
                 for a in fn.args.posonlyargs + fn.args.args + fn.args.kwonlyargs
             ]
-            out_params = params[in_specs.count :]
+            first_out = (n_prefetch or 0) + in_specs.count
+            out_params = params[first_out : first_out + len(contract)]
             for node in ast.walk(fn):
                 if not (
                     isinstance(node, ast.Assign)

@@ -134,7 +134,12 @@ class CheckpointManager:
     # ------------------------------------------------------------------ save
 
     def save(self, step: int, state: PyTree, *, blocking: bool = False) -> None:
-        """Snapshot is taken synchronously; compression+IO go async."""
+        """Snapshot is taken synchronously; compression+IO go async.
+
+        A blocking save (``blocking=True`` or ``async_save=False``) raises
+        its own error.  An async save's error is raised by the next
+        :meth:`wait` or :meth:`save`.
+        """
         self.wait()
         flat = _flatten(state)
         is_base = (
@@ -151,28 +156,31 @@ class CheckpointManager:
         prev_step = None if is_base else self._last_save_step
 
         def work():
+            self._write(
+                step, flat, is_base, base_flat, base_step,
+                prev_flat, prev_step,
+            )
+            if is_base:
+                self._last_base_step = step
+                self._last_base_flat = flat
+            if self.cfg.moment_keys:
+                self._last_moment_flat = {
+                    k: v for k, v in flat.items()
+                    if is_moment_path(k, self.cfg.moment_keys)
+                }
+                self._last_save_step = step
+            self._gc()
+
+        def work_async():
             try:
-                self._write(
-                    step, flat, is_base, base_flat, base_step,
-                    prev_flat, prev_step,
-                )
-                if is_base:
-                    self._last_base_step = step
-                    self._last_base_flat = flat
-                if self.cfg.moment_keys:
-                    self._last_moment_flat = {
-                        k: v for k, v in flat.items()
-                        if is_moment_path(k, self.cfg.moment_keys)
-                    }
-                    self._last_save_step = step
-                self._gc()
+                work()
             except BaseException as e:          # surfaced on next wait()
                 self._errors.append(e)
 
         if blocking or not self.cfg.async_save:
             work()
         else:
-            self._thread = threading.Thread(target=work, daemon=True)
+            self._thread = threading.Thread(target=work_async, daemon=True)
             self._thread.start()
 
     def wait(self) -> None:

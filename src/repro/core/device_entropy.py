@@ -287,7 +287,7 @@ def _pack_jobs(
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels import bitpack
+    from repro.kernels import bitpack, ops
 
     c = len(jobs)
     pids = np.empty(c, dtype=np.int32)
@@ -309,7 +309,7 @@ def _pack_jobs(
         jnp.asarray(len_tables),
         jnp.asarray(code_tables),
         chunk_syms=chunk_bytes,
-        interpret=jax.default_backend() != "tpu",
+        interpret=ops.interpret_mode(),
     )
     # The one device→host transfer: packed words + true bit counts together.
     words_h, nbits_h = jax.device_get((words, nbits))
@@ -537,7 +537,7 @@ def _unpack_jobs(
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels import huffdecode
+    from repro.kernels import huffdecode, ops
 
     words, pids, counts, sizes = _pack_words(
         jobs, entries_all, payloads_all, chunk_bytes
@@ -549,7 +549,7 @@ def _unpack_jobs(
         jnp.asarray(counts),
         jnp.asarray(luts),
         chunk_bytes=chunk_bytes,
-        interpret=jax.default_backend() != "tpu",
+        interpret=ops.interpret_mode(),
     )
     cursors_h = np.asarray(jax.device_get(cursors), dtype=np.int64)
     _check_cursors(jobs, payloads_all, sizes, cursors_h)
@@ -792,7 +792,7 @@ class PayloadFeed:
         import jax
         import jax.numpy as jnp
 
-        from repro.kernels import huffdecode
+        from repro.kernels import huffdecode, ops
 
         cb = params.chunk_bytes
         if not supports_decode(cb):
@@ -801,7 +801,7 @@ class PayloadFeed:
                 f"(chunk_bytes % 4 == 0, got {cb}) and an importable jax"
             )
         self.chunk_bytes = cb
-        self._interpret = jax.default_backend() != "tpu"
+        self._interpret = ops.interpret_mode()
         # Decode-time assembly needs only (method, raw_len) per chunk; the
         # payload bytes themselves are not retained host-side.
         self._meta = [

@@ -154,10 +154,7 @@ def _on_accelerator(leaf: Any) -> bool:
 
     if not isinstance(leaf, jax.Array):
         return False
-    try:
-        return any(d.platform != "cpu" for d in leaf.devices())
-    except Exception:
-        return False
+    return any(d.platform != "cpu" for d in leaf.devices())
 
 
 def resolve(
@@ -298,7 +295,7 @@ def produce_planes_batched(
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels import fused_plane
+    from repro.kernels import fused_plane, ops
 
     cb = params.chunk_bytes                      # elements per (plane) chunk
     align = (
@@ -351,7 +348,7 @@ def produce_planes_batched(
 
     planes2d, hists_dev = fused_plane.plane_producer(
         x2, base2, itemsize=layout.itemsize, chunk_elems=cb,
-        interpret=jax.default_backend() != "tpu",
+        interpret=ops.interpret_mode(),
     )
     # The one device→host transfer of the whole batch: planed uint8 buffers
     # + probe histograms together.

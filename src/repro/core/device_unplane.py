@@ -96,10 +96,7 @@ def _accelerator_attached() -> bool:
         return False
     import jax
 
-    try:
-        return jax.default_backend() != "cpu"
-    except Exception:  # pragma: no cover - defensive
-        return False
+    return jax.default_backend() != "cpu"
 
 
 def resolve(
@@ -240,7 +237,7 @@ def consume_planes_batched(
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels import fused_unplane
+    from repro.kernels import fused_unplane, ops
 
     total = sum(sizes)
     if total == 0:                               # every leaf empty: no dispatch
@@ -298,7 +295,7 @@ def consume_planes_batched(
 
     x2 = fused_unplane.plane_consumer(
         tuple(dev_planes), base2, itemsize=layout.itemsize,
-        interpret=jax.default_backend() != "tpu",
+        interpret=ops.interpret_mode(),
     )
     if device_resident:
         # Zero-bounce: per-leaf element slices stay on device for the

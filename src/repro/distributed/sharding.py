@@ -36,15 +36,9 @@ DEFAULT_RULES: Dict[str, Any] = {
 }
 
 
-def _abstract_mesh():
-    """Current abstract mesh, or None on jax versions without the API."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    return get() if get is not None else None
-
-
 def _current_mesh_axes() -> Optional[Tuple[str, ...]]:
-    mesh = _abstract_mesh()
-    if mesh is not None and mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.axis_names:
         return tuple(mesh.axis_names)
     try:  # legacy `with mesh:` context (what launch/dryrun.py uses)
         from jax._src.mesh import thread_resources
@@ -93,8 +87,8 @@ def axis_size(name: str) -> int:
             return dict(pm.shape).get(name, 1)
     except Exception:
         pass
-    am = _abstract_mesh()
-    if am is not None and am.axis_names:
+    am = jax.sharding.get_abstract_mesh()
+    if am.axis_names:
         return dict(am.shape).get(name, 1)
     return 1
 

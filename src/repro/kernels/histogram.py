@@ -1,11 +1,14 @@
-"""Pallas TPU kernel: 256-bin byte histogram.
+"""Pallas TPU kernel: 256-bin byte histograms, per codec chunk.
 
 Histograms drive ZipNN's table building and compressibility probes.  CUDA
-would use atomic scatter-adds; TPU has no atomics, so the TPU-native
-formulation is *compare-and-reduce*: each grid step compares its block
-against bin indices and accumulates per-bin counts into a revisited output
-block.  Bins are processed in groups of 32 to bound the comparison
-matrix's VMEM footprint (32 × block ≈ 2 MiB int32 at the default block).
+would use atomic scatter-adds; TPU has no atomics and no vector scatter, so
+the TPU-native formulation is *compare-and-reduce*: each grid step compares
+its (HIST_ROWS, 128) block against every bin and adds the per-lane counts
+(a sum over rows) into a (256, 128) scratch accumulator.  On the chunk's
+last block the accumulator is transposed and summed over lanes into the
+chunk's 256-bin row.  Output blocks are (1, 256) rows of a
+(chunks, 1, 256) array, which the TPU's (8, 128) tiling accepts because the
+block spans the array's last two dimensions.
 """
 
 from __future__ import annotations
@@ -15,59 +18,35 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
-HIST_ROWS = 128            # u8 block: 16 KiB; compare matrix: 32×16384 i32 = 2 MiB
-BIN_GROUPS = 8             # 8 × 32 bins
+HIST_ROWS = 128            # u8 block: 16 KiB
 
 
-def _hist_kernel(x_ref, out_ref):
-    @pl.when(pl.program_id(0) == 0)
+def _chunk_hist_kernel(x_ref, out_ref, acc_ref):
+    # Grid (chunk, block-within-chunk): the accumulator is reset on a
+    # chunk's first block and emitted on its last.
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32).reshape(1, -1)
+    x = x_ref[...].astype(jnp.int32)
 
-    def body(g, carry):
-        bins = g * 32 + jax.lax.iota(jnp.int32, 32).reshape(32, 1)
-        part = jnp.sum((x == bins).astype(jnp.int32), axis=1)
-        out_ref[pl.ds(g * 32, 32)] += part
+    def body(b, carry):
+        acc_ref[pl.ds(b, 1), :] += jnp.sum(
+            (x == b).astype(jnp.int32), axis=0, keepdims=True
+        )
         return carry
 
-    jax.lax.fori_loop(0, BIN_GROUPS, body, 0)
+    jax.lax.fori_loop(0, 256, body, 0)
 
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def histogram_2d(x: jax.Array, *, interpret: bool = True) -> jax.Array:
-    """uint8[M, 128] (M % HIST_ROWS == 0) → int32[256] counts."""
-    m = x.shape[0]
-    return pl.pallas_call(
-        _hist_kernel,
-        grid=(m // HIST_ROWS,),
-        in_specs=[pl.BlockSpec((HIST_ROWS, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((256,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((256,), jnp.int32),
-        interpret=interpret,
-    )(x)
-
-
-def _chunk_hist_kernel(x_ref, out_ref):
-    # Grid (chunk, block-within-chunk): the output block for chunk ``i`` is
-    # revisited across the inner grid dimension, initialized on its first
-    # visit — same revisit-and-accumulate pattern as ``_hist_kernel``.
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    x = x_ref[...].astype(jnp.int32).reshape(1, -1)
-
-    def body(g, carry):
-        bins = g * 32 + jax.lax.iota(jnp.int32, 32).reshape(32, 1)
-        part = jnp.sum((x == bins).astype(jnp.int32), axis=1)
-        out_ref[0, pl.ds(g * 32, 32)] += part
-        return carry
-
-    jax.lax.fori_loop(0, BIN_GROUPS, body, 0)
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _emit():
+        counts = jnp.sum(acc_ref[...].T, axis=0, keepdims=True)
+        out_ref[...] = counts.reshape(out_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_rows", "interpret"))
@@ -87,13 +66,20 @@ def chunk_histogram_2d(
     m = x.shape[0]
     n_chunks = m // chunk_rows
     blocks = chunk_rows // HIST_ROWS
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _chunk_hist_kernel,
         grid=(n_chunks, blocks),
         in_specs=[
             pl.BlockSpec((HIST_ROWS, LANES), lambda i, j: (i * blocks + j, 0))
         ],
-        out_specs=pl.BlockSpec((1, 256), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_chunks, 256), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, 256), lambda i, j: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_chunks, 1, 256), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((256, LANES), jnp.int32)],
         interpret=interpret,
     )(x)
+    return out.reshape(n_chunks, 256)
+
+
+def histogram_2d(x: jax.Array, *, interpret: bool = True) -> jax.Array:
+    """uint8[M, 128] (M % HIST_ROWS == 0) → int32[256] counts."""
+    return chunk_histogram_2d(x, chunk_rows=x.shape[0], interpret=interpret)[0]

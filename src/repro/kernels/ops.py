@@ -18,7 +18,13 @@ from . import bitpack, bytegroup, histogram, xor_delta
 LANES = 128
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
+    """Whether the kernels run in the Pallas interpreter.
+
+    They compile for the chip only where JAX's default backend is a TPU;
+    on any other platform they run interpreted, which checks bytes but says
+    nothing about speed.  Every kernel launch in the package asks here.
+    """
     return jax.default_backend() != "tpu"
 
 
@@ -35,35 +41,35 @@ def _pad_2d(x: jnp.ndarray, rows: int) -> Tuple[jnp.ndarray, int]:
 def bytegroup_bf16(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """uint16[N] → (exponent uint8[N], frac|sign uint8[N])."""
     x2, n = _pad_2d(x, bytegroup.BF16_ROWS)
-    exp, frac = bytegroup.bytegroup_bf16_2d(x2, interpret=_interpret())
+    exp, frac = bytegroup.bytegroup_bf16_2d(x2, interpret=interpret_mode())
     return exp.reshape(-1)[:n], frac.reshape(-1)[:n]
 
 
 def ungroup_bf16(exp: jax.Array, frac: jax.Array) -> jax.Array:
     e2, n = _pad_2d(exp, bytegroup.BF16_ROWS)
     f2, _ = _pad_2d(frac, bytegroup.BF16_ROWS)
-    x = bytegroup.ungroup_bf16_2d(e2, f2, interpret=_interpret())
+    x = bytegroup.ungroup_bf16_2d(e2, f2, interpret=interpret_mode())
     return x.reshape(-1)[:n]
 
 
 def bytegroup_fp32(x: jax.Array) -> Tuple[jax.Array, ...]:
     """uint32[N] → 4 × uint8[N] planes (plane 0 = exponent)."""
     x2, n = _pad_2d(x, bytegroup.FP32_ROWS)
-    planes = bytegroup.bytegroup_fp32_2d(x2, interpret=_interpret())
+    planes = bytegroup.bytegroup_fp32_2d(x2, interpret=interpret_mode())
     return tuple(p.reshape(-1)[:n] for p in planes)
 
 
 def ungroup_fp32(*planes: jax.Array) -> jax.Array:
     padded = [_pad_2d(p, bytegroup.FP32_ROWS)[0] for p in planes]
     n = planes[0].shape[0]
-    x = bytegroup.ungroup_fp32_2d(*padded, interpret=_interpret())
+    x = bytegroup.ungroup_fp32_2d(*padded, interpret=interpret_mode())
     return x.reshape(-1)[:n]
 
 
 def byte_histogram(x: jax.Array) -> jax.Array:
     """uint8[N] → int32[256].  Padding bytes (zeros) are subtracted out."""
     x2, n = _pad_2d(x, histogram.HIST_ROWS)
-    hist = histogram.histogram_2d(x2, interpret=_interpret())
+    hist = histogram.histogram_2d(x2, interpret=interpret_mode())
     pad = x2.size - n
     return hist.at[0].add(-pad)
 
@@ -72,7 +78,7 @@ def xor_delta_u32(a: jax.Array, b: jax.Array) -> Tuple[jax.Array, jax.Array]:
     """(uint32[N],)² → (delta uint32[N], changed-byte count int32[])."""
     a2, n = _pad_2d(a, xor_delta.XOR_ROWS)
     b2, _ = _pad_2d(b, xor_delta.XOR_ROWS)
-    d, cnt = xor_delta.xor_delta_2d(a2, b2, interpret=_interpret())
+    d, cnt = xor_delta.xor_delta_2d(a2, b2, interpret=interpret_mode())
     return d.reshape(-1)[:n], cnt[0]
 
 
@@ -109,7 +115,7 @@ def huffman_encode_chunks(
         jnp.asarray(lens, dtype=jnp.int32),
         jnp.asarray(codes, dtype=jnp.int32),
         chunk_syms=chunk_syms,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
     words = np.asarray(words)
     nbits = np.asarray(nbits)

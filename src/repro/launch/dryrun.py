@@ -237,17 +237,8 @@ def _lower_and_compile(cfg, cell, mesh):
     return compiled
 
 
-def _cost_analysis(compiled) -> Dict[str, float]:
-    """Version-portable compiled.cost_analysis(): older jax returns a
-    one-element list of dicts, newer jax the dict itself."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _costs_of(compiled) -> Dict[str, float]:
-    cost = _cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
     out = {"flops": float(cost.get("flops", 0.0)),
            "bytes_accessed": float(cost.get("bytes accessed", 0.0))}
@@ -318,7 +309,7 @@ def run_cell(
     t_lower = 0.0
 
     mem = compiled.memory_analysis()
-    cost = _cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = collective_bytes(compiled.as_text())
 
     # Accounting terms feed the single-pod roofline table only; the

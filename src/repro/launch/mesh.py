@@ -14,20 +14,13 @@ SINGLE_POD = (16, 16)                 # 256 chips (v5e pod slice)
 MULTI_POD = (2, 16, 16)               # 2 pods × 256 = 512 chips
 
 
-def _mk(shape, axes):
-    # Pin Auto axis types where the API exists: the jax 0.9 default flips to
-    # Explicit.  Older jax (< 0.4.38) has neither jax.sharding.AxisType nor
-    # the axis_types= kwarg — there Auto is the only behavior, so plain
-    # make_mesh is equivalent.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(axis_type.Auto,) * len(axes)
-            )
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+def _mk(shape, axes, devices=None):
+    # Auto axes: the sharding rules place parameters and GSPMD propagates
+    # the rest (make_mesh defaults to Explicit axes).
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -39,6 +32,12 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_host_mesh():
     """1-device mesh for CPU smoke paths."""
     return _mk((1, 1), ("data", "model"))
+
+
+def make_model_mesh(n: int):
+    """A ('data', 'model') mesh of the first ``n`` devices, all on 'model':
+    the tensor-parallel layout of one host's chips."""
+    return _mk((1, n), ("data", "model"), devices=jax.devices()[:n])
 
 
 def n_chips(mesh) -> int:

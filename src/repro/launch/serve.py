@@ -40,8 +40,11 @@ def main() -> None:
     model = build_model(cfg)
 
     if args.ckpt_dir:
-        mgr = CheckpointManager(CheckpointConfig(args.ckpt_dir))
-        step, tree = mgr.restore()
+        # 'auto' decodes on the device exactly when the process holds an
+        # accelerator, and device_resident keeps the leaves there; on a CPU
+        # host the restore stays the numpy path.
+        mgr = CheckpointManager(CheckpointConfig(args.ckpt_dir, backend="auto"))
+        step, tree = mgr.restore(device_resident=True)
         params = jax.tree_util.tree_map(jnp.asarray, tree["params"])
         print(f"[serve] restored step {step} from ZipNN checkpoint")
     else:

@@ -376,6 +376,11 @@ class Model:
                 entries (B, 1, …) — the slot write happens once, below, so
                 the multi-GiB stacks never thread through scan carries/ys."""
                 if not cfg.scan_layers:
+                    # The barrier keeps the compiler from fusing across a
+                    # layer boundary, so each layer rounds exactly as a
+                    # layer-at-a-time executor (the compressed serving
+                    # ring) computes it.  On TPU a scan body, or layers
+                    # fused together, round differently.
                     n = jax.tree_util.tree_leaves(stack)[0].shape[0]
                     outs0, outs1 = [], []
                     for i in range(n):
@@ -383,6 +388,7 @@ class Model:
                         x, (u0, u1) = block_decode(
                             lp, x, (caches[0][i], caches[1][i]), pos, cfg
                         )
+                        x, u0, u1 = jax.lax.optimization_barrier((x, u0, u1))
                         outs0.append(u0)
                         outs1.append(u1)
                     return x, (jnp.stack(outs0), jnp.stack(outs1))
@@ -426,6 +432,7 @@ class Model:
                     x, (st, cv) = blocks.mamba_block_decode(
                         lp, x, (state["ssm_state"][i], state["ssm_conv"][i]), pos, cfg
                     )
+                    x, st, cv = jax.lax.optimization_barrier((x, st, cv))
                     outs_s.append(st)
                     outs_c.append(cv)
                 new_state.update(

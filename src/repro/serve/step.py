@@ -120,7 +120,11 @@ def make_serve_step(model: Model) -> Callable:
 # same block functions, the same eager front (embed + learned positions) and
 # tail (final norm + unembed), the same single post-loop cache write.  These
 # helpers are that shared skeleton — one source of truth for the layer plan
-# and the bit-identity claim.
+# and the bit-identity claim.  The bits match decode_step's layer-at-a-time
+# form (``scan_layers=False``, a barrier between layers).  On the CPU they
+# also match the scanned step; on a TPU the compiler rounds a scan body
+# differently from a standalone layer, so the scanned step differs in the
+# last bits.
 # ---------------------------------------------------------------------------
 
 
@@ -139,8 +143,9 @@ def _layer_plan(cfg) -> list:
 
 def _block_kinds(cfg) -> Dict[str, Callable]:
     """One compile per block *kind*, shared by every layer (all layers of a
-    stack have identical shapes) — the same block functions decode_step's
-    scan body runs, so the math is bit-identical to the fused step."""
+    stack have identical shapes) — the same block functions decode_step
+    runs per layer, so the math is bit-identical to its layer-at-a-time
+    form (``scan_layers=False``)."""
     from repro.models import blocks
 
     return {
@@ -193,7 +198,8 @@ def make_kv_tiered_serve_step(model: Model, params, kv_store) -> Callable:
     ``serve_step(tokens) -> logits`` — the cache lives in ``kv_store``
     (hot suffix + compressed cold blocks) instead of the state dict, and
     advances as a side effect of the call.  Logits are **bit-identical**
-    to ``model.decode_step`` over the untiered cache: each layer's block
+    to ``model.decode_step`` (layer at a time, see the note above
+    ``_layer_plan``) over the untiered cache: each layer's block
     function receives the store's reassembled full-length caches
     (byte-identical arrays — see ``serve/kvcache.py``), and the new-token
     entries flow through the same masked one-hot write.  Peak cache
@@ -259,9 +265,10 @@ def make_compressed_serve_step(
     buffers when the matmuls retire.
 
     Logits and new state are **bit-identical** to the uncompressed
-    ``model.decode_step``: the per-layer block functions are the same code
+    ``model.decode_step`` run layer at a time (see the note above
+    ``_layer_plan``): the per-layer block functions are the same code
     decode_step runs (jit-compiled once per block *kind*, reused by every
-    layer — identical math to the scan body), the cache slot-write happens
+    layer), the cache slot-write happens
     once after the loop exactly as in decode_step, and the payload decode
     itself is byte-identical across ``backend`` × ``entropy_backend`` ×
     ``threads`` (the knob contract; ``prefetch=False`` gives the

@@ -422,7 +422,7 @@ _KERNEL = """
     def _hist_kernel(x_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    def histogram_2d(x, *, interpret: bool = True):
+    def chunk_histogram_2d(x, *, interpret: bool = True):
         m = x.shape[0]
         return pl.pallas_call(
             _hist_kernel,
@@ -486,6 +486,55 @@ def test_kernel_arity_mismatch():
     )
     v = lint(code, KERN, [kernel_contract])
     assert "kernel-arity" in rules_of(v)
+
+
+_PREFETCH_KERNEL = """
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def _decode_kernel({params}):
+        pass
+
+    def huffdecode_chunks_multi(ids, words, *, interpret: bool = True):
+        return pl.pallas_call(
+            _decode_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(8,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=[
+                    pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pltpu.SMEM),
+                ],
+                scratch_shapes=[
+                    pltpu.SMEM((1024,), jnp.int32),
+                    pltpu.SemaphoreType.DMA((2,)),
+                ],
+            ),
+            out_shape=[
+                jax.ShapeDtypeStruct((8192,), jnp.int32),
+                jax.ShapeDtypeStruct((8,), jnp.int32),
+            ],
+            interpret=interpret,
+        )(ids, words)
+"""
+
+
+@pytest.mark.parametrize(
+    "params, flagged",
+    [
+        ("ids_ref, words_hbm, out_hbm, cur_ref, buf_s, sem", False),
+        ("ids_ref, words_hbm, out_hbm, cur_ref, sem", True),     # no scratch
+        ("words_hbm, out_hbm, cur_ref, buf_s, sem", True),      # no prefetch
+    ],
+)
+def test_kernel_arity_counts_prefetch_and_scratch(params, flagged):
+    """Refs of a grid_spec= call: scalar prefetch, inputs, outputs, scratch."""
+    v = lint(_PREFETCH_KERNEL.format(params=params), KERN, [kernel_contract])
+    assert ("kernel-arity" in rules_of(v)) is flagged
+    assert "kernel-dtype" not in rules_of(v)
 
 
 # ---------------------------------------------------------------------------

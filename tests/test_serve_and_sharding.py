@@ -172,3 +172,40 @@ class TestMesh:
         mesh = make_host_mesh()
         assert n_chips(mesh) == 1
         assert tuple(mesh.axis_names) == ("data", "model")
+
+    def test_make_model_mesh(self):
+        from repro.launch.mesh import make_model_mesh, n_chips
+
+        mesh = make_model_mesh(1)
+        assert n_chips(mesh) == 1
+        assert dict(mesh.shape) == {"data": 1, "model": 1}
+        assert set(mesh.axis_types) == {jax.sharding.AxisType.Auto}
+
+
+class TestServeLauncher:
+    def test_serves_from_checkpoint(self, tmp_path, monkeypatch, capsys):
+        """launch/serve.py restores a ZipNN checkpoint and generates from
+        it; on a CPU host the restore stays on the numpy path."""
+        import sys
+
+        from repro.checkpoint import CheckpointConfig, CheckpointManager
+        from repro.launch import serve
+
+        cfg = get_config("repro_gpt_100m").reduced()
+        params = build_model(cfg).init(jax.random.key(0))
+        CheckpointManager(CheckpointConfig(str(tmp_path))).save(
+            5, {"params": params}, blocking=True
+        )
+        argv = ["serve", "--arch", "repro_gpt_100m", "--reduced",
+                "--ckpt-dir", str(tmp_path), "--batch", "2",
+                "--prompt-len", "4", "--gen", "3"]
+        monkeypatch.setattr(sys, "argv", argv)
+        serve.main()
+        out = capsys.readouterr().out
+        assert "restored step 5" in out
+        prompt = jnp.asarray(
+            np.random.default_rng(0).integers(0, cfg.vocab_size, (2, 4)), jnp.int32
+        )
+        want, _ = greedy_generate(build_model(cfg), params, prompt, 3)
+        assert f"first sequence: {np.asarray(want[0]).tolist()}" in out
+

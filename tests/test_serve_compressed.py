@@ -75,6 +75,16 @@ class TestCompressedServe:
         assert store.resident_count == 0          # every slot released
         assert store.comp_bytes < store.raw_bytes # actually compressed
 
+    @pytest.mark.parametrize("arch", ["repro_gpt_100m", "mamba2_130m"])
+    def test_ring_bit_identical_to_layer_at_a_time_step(self, arch):
+        """The reference the ring matches on every platform, the TPU
+        included: decode_step one layer at a time (scan_layers=False)."""
+        cfg, model, params = _tiny(arch)
+        unrolled = build_model(dataclasses.replace(cfg, scan_layers=False))
+        store = CompressedParamStore.from_params(params)
+        cstep = make_compressed_serve_step(model, store)
+        assert _lockstep(cfg, unrolled, params, cstep)
+
     @pytest.mark.parametrize("ring,prefetch", [(1, False), (2, True), (3, True)])
     def test_ring_depths(self, ring, prefetch):
         cfg, model, params = _tiny("repro_gpt_100m")

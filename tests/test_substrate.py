@@ -169,6 +169,29 @@ class TestCheckpointManager:
         mgr.wait()
         assert mgr.latest_step() == 7
 
+    @pytest.mark.parametrize(
+        "async_save, blocking", [(False, True), (True, True), (True, False)]
+    )
+    def test_failed_save_raises(self, tmp_path, monkeypatch, async_save, blocking):
+        """A blocking save raises its own error; an async one at wait()."""
+        mgr = CheckpointManager(
+            CheckpointConfig(str(tmp_path), async_save=async_save)
+        )
+
+        def disk_full(*args, **kwargs):
+            raise OSError("disk full")
+
+        monkeypatch.setattr(mgr, "_write", disk_full)
+        if blocking:
+            with pytest.raises(OSError, match="disk full"):
+                mgr.save(1, self._state(1), blocking=True)
+        else:
+            mgr.save(1, self._state(1))
+            with pytest.raises(RuntimeError, match="disk full"):
+                mgr.wait()
+        mgr.wait()                      # nothing stale is left to raise
+        assert mgr.latest_step() is None
+
     def test_retention_gc(self, tmp_path):
         mgr = CheckpointManager(
             CheckpointConfig(str(tmp_path), base_every=2, keep_bases=1, async_save=False)
