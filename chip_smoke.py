@@ -64,33 +64,24 @@ ARCH = "qwen15_4b"
 BATCH, PROMPT, NEW_TOKENS, RING_STEPS = 4, 16, 8, 4
 
 
+def compile_s() -> float:
+    """Seconds JAX has spent lowering and compiling, from the program's
+    ``compile_s`` counter (JAX's own compile-duration events)."""
+    from repro.core import tracing
+
+    return tracing.counters()["compile_s"]
+
+
 class Phase:
-    """Times one phase and prints its line; compile seconds come from
-    JAX's own lowering and compile-duration events."""
-
-    _compile_s = 0.0
-
-    @classmethod
-    def listen(cls, jax) -> None:
-        # Lowering and XLA compilation; tracing is left out because nested
-        # jits report their traces inside the outer one's.
-        events = {
-            "/jax/core/compile/jaxpr_to_mlir_module_duration",
-            "/jax/core/compile/backend_compile_duration",
-        }
-
-        def on_duration(event, duration, **_):
-            if event in events:
-                cls._compile_s += duration
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
+    """Times one phase and prints its line, with the seconds spent
+    compiling split out."""
 
     def __init__(self, name: str):
         self.name = name
 
     def __enter__(self) -> "Phase":
         self.t0 = time.perf_counter()
-        self.c0 = Phase._compile_s
+        self.c0 = compile_s()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -100,7 +91,7 @@ class Phase:
 
     def done(self, what: str, **numbers) -> None:
         wall = time.perf_counter() - self.t0
-        comp = Phase._compile_s - self.c0
+        comp = compile_s() - self.c0
         fields = " ".join(f"{k}={v}" for k, v in numbers.items())
         print(
             f"phase={self.name} ok wall_s={wall:.2f} compile_s={comp:.2f} "
@@ -409,8 +400,8 @@ def main(argv=None) -> int:
 
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
-    Phase.listen(jax)
     sys.path.insert(0, str(ROOT / "src"))
+    compile_s()                     # start counting compiles from here
     devs = device_phase(jax, args.chips)
 
     zcfg = smoke_config()

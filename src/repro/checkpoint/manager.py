@@ -38,7 +38,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.core import zipnn
+from repro.core import tracing, zipnn
 from repro.optim.adamw import MOMENT_KEYS, is_moment_path
 
 PyTree = Any
@@ -95,7 +95,7 @@ def _flatten(tree: PyTree) -> Dict[str, np.ndarray]:
     out = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
         key = "/".join(str(getattr(k, "key", getattr(k, "idx", k))) for k in path)
-        out[key] = np.asarray(jax.device_get(leaf))
+        out[key] = np.asarray(tracing.fetch(leaf))
     return out
 
 
@@ -140,8 +140,13 @@ class CheckpointManager:
         its own error.  An async save's error is raised by the next
         :meth:`wait` or :meth:`save`.
         """
+        with tracing.operation("znn.ckpt.save"):
+            self._save(step, state, blocking)
+
+    def _save(self, step: int, state: PyTree, blocking: bool) -> None:
         self.wait()
-        flat = _flatten(state)
+        with tracing.span("znn.ckpt.snapshot"):
+            flat = _flatten(state)
         is_base = (
             self._save_count % self.cfg.base_every == 0
             or self._last_base_flat is None
@@ -171,9 +176,12 @@ class CheckpointManager:
                 self._last_save_step = step
             self._gc()
 
+        op = tracing.current_op()
+
         def work_async():
             try:
-                work()
+                with tracing.joined(op):
+                    work()
             except BaseException as e:          # surfaced on next wait()
                 self._errors.append(e)
 
@@ -266,7 +274,8 @@ class CheckpointManager:
                 else:
                     ct = zipnn.compress_array(arr, self.cfg.zipnn)
                     kind = "full"
-                f.write(ct.blob)
+                with tracing.span("znn.ckpt.write"):
+                    f.write(ct.blob)
                 entries.append(
                     {
                         "key": key,
@@ -280,8 +289,9 @@ class CheckpointManager:
                     }
                 )
                 offset += len(ct.blob)
-            f.flush()
-            os.fsync(f.fileno())
+            with tracing.span("znn.ckpt.write"):
+                f.flush()
+                os.fsync(f.fileno())
         manifest = {
             "step": step,
             "kind": "base" if is_base else "delta",
@@ -291,26 +301,28 @@ class CheckpointManager:
             "raw_bytes": sum(e["raw"] for e in entries),
             "entries": entries,
         }
-        with open(os.path.join(tmp, "manifest.json"), "w") as f:
-            json.dump(manifest, f)
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, final)                  # atomic publish
+        with tracing.span("znn.ckpt.write"):
+            with open(os.path.join(tmp, "manifest.json"), "w") as f:
+                json.dump(manifest, f)
+                f.flush()
+                os.fsync(f.fileno())
+            os.replace(tmp, final)              # atomic publish
 
     # --------------------------------------------------------------- restore
 
     def _scan(self) -> List[Tuple[int, str, Optional[int]]]:
         out = []
-        for name in sorted(os.listdir(self.cfg.directory)):
-            if not name.startswith("step_"):
-                continue
-            mpath = os.path.join(self.cfg.directory, name, "manifest.json")
-            try:
-                with open(mpath) as f:
-                    m = json.load(f)
-                out.append((m["step"], m["kind"], m.get("base_step")))
-            except (OSError, json.JSONDecodeError):
-                continue                        # torn checkpoint: skip
+        with tracing.span("znn.ckpt.scan"):
+            for name in sorted(os.listdir(self.cfg.directory)):
+                if not name.startswith("step_"):
+                    continue
+                mpath = os.path.join(self.cfg.directory, name, "manifest.json")
+                try:
+                    with open(mpath) as f:
+                        m = json.load(f)
+                    out.append((m["step"], m["kind"], m.get("base_step")))
+                except (OSError, json.JSONDecodeError):
+                    continue                    # torn checkpoint: skip
         return sorted(out)
 
     def latest_step(self) -> Optional[int]:
@@ -332,10 +344,11 @@ class CheckpointManager:
         if step in _cache:
             return _cache[step]
         d = os.path.join(self.cfg.directory, f"step_{step}")
-        with open(os.path.join(d, "manifest.json")) as f:
-            manifest = json.load(f)
-        with open(os.path.join(d, "data.bin"), "rb") as f:
-            data = f.read()
+        with tracing.span("znn.ckpt.read"):
+            with open(os.path.join(d, "manifest.json")) as f:
+                manifest = json.load(f)
+            with open(os.path.join(d, "data.bin"), "rb") as f:
+                data = f.read()
         base_flat = None
         if manifest["kind"] == "delta":
             # The base rides the same residence as the restore target: a
@@ -356,7 +369,9 @@ class CheckpointManager:
         full_cts = []
         for e in manifest["entries"]:
             blob = data[e["offset"] : e["offset"] + e["size"]]
-            if zlib.crc32(blob) != e["crc"]:
+            with tracing.span("znn.ckpt.entry_crc"):
+                crc = zlib.crc32(blob)
+            if crc != e["crc"]:
                 raise IOError(f"CRC mismatch in step_{step}:{e['key']}")
             ct = zipnn.CompressedTensor(blob, e["dtype"], tuple(e["shape"]))
             if e["kind"] == "delta":
@@ -403,14 +418,17 @@ class CheckpointManager:
         device (see ``zipnn.decompress_array``) — bits identical, zero
         device→host bounce; host-resolved leaves still restore as numpy.
         """
-        candidates = [s for s, _, _ in self._scan() if step is None or s <= step]
-        for s in reversed(candidates):
-            try:
-                return s, _unflatten(
-                    self._load_flat(s, device_resident=device_resident)
-                )
-            except (IOError, OSError, KeyError):
-                continue
+        with tracing.operation("znn.ckpt.restore"):
+            candidates = [
+                s for s, _, _ in self._scan() if step is None or s <= step
+            ]
+            for s in reversed(candidates):
+                try:
+                    return s, _unflatten(
+                        self._load_flat(s, device_resident=device_resident)
+                    )
+                except (IOError, OSError, KeyError):
+                    continue
         raise FileNotFoundError(f"no valid checkpoint in {self.cfg.directory}")
 
     def shard_restore(self, step: Optional[int], mesh, specs: PyTree) -> Tuple[int, PyTree]:

@@ -59,13 +59,12 @@ regardless of the configured encode backend.
 from __future__ import annotations
 
 import functools
-import threading
 import zlib
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import bitlayout, codec, huffman
+from . import bitlayout, codec, huffman, tracing
 
 __all__ = [
     "BACKENDS",
@@ -104,32 +103,24 @@ LUT_CACHE_SIZE = 64
 # ---------------------------------------------------------------------------
 #
 # Every payload-sized host→device upload on this module's encode/decode
-# paths is tallied here: HUFF symbol uploads (_pack_jobs host path), packed
-# word uploads (_unpack_jobs / PayloadFeed build) and the non-HUFF splice
-# upload.  The counters are the test hook behind the device-resident feed's
-# headline contract — zero per-token payload uploads after warmup — and
-# count bookkeeping only: they never touch the data path.
+# paths is tallied in the program's counters (core/tracing.py): HUFF symbol
+# uploads (_pack_jobs host path), packed word uploads (_unpack_jobs /
+# PayloadFeed build) and the non-HUFF splice upload.  The counters are the
+# test hook behind the device-resident feed's headline contract — zero
+# per-token payload uploads after warmup — and count bookkeeping only:
+# they never touch the data path.
 
-_transfer_lock = threading.Lock()
-_transfer_stats: Dict[str, int] = {"payload_uploads": 0, "payload_bytes": 0}
-
-
-def _count_payload_upload(nbytes: int) -> None:
-    with _transfer_lock:
-        _transfer_stats["payload_uploads"] += 1
-        _transfer_stats["payload_bytes"] += int(nbytes)
+_PAYLOAD_COUNTERS = ("payload_uploads", "payload_bytes")
 
 
 def transfer_stats() -> Dict[str, int]:
     """Snapshot of payload host→device upload counters (test hook)."""
-    with _transfer_lock:
-        return dict(_transfer_stats)
+    now = tracing.counters()
+    return {k: int(now[k]) for k in _PAYLOAD_COUNTERS}
 
 
 def reset_transfer_stats() -> None:
-    with _transfer_lock:
-        for k in _transfer_stats:
-            _transfer_stats[k] = 0
+    tracing.reset_counters(_PAYLOAD_COUNTERS)
 
 
 def is_available() -> bool:
@@ -284,7 +275,6 @@ def _pack_jobs(
     host — the rows carry the identical zero padding, so the packed bits
     cannot differ.
     """
-    import jax
     import jax.numpy as jnp
 
     from repro.kernels import bitpack, ops
@@ -301,18 +291,20 @@ def _pack_jobs(
             syms[k * chunk_bytes : k * chunk_bytes + size] = (
                 planes[p][start : start + size]
             )
-        _count_payload_upload(syms.nbytes)
+        tracing.count_payload_upload(syms.nbytes)
         syms_dev = jnp.asarray(syms)
-    words, nbits = bitpack.bitpack_encode_chunks_multi(
-        syms_dev,
-        jnp.asarray(pids),
-        jnp.asarray(len_tables),
-        jnp.asarray(code_tables),
-        chunk_syms=chunk_bytes,
-        interpret=ops.interpret_mode(),
-    )
+    with tracing.span("znn.codec.launch"):
+        words, nbits = bitpack.bitpack_encode_chunks_multi(
+            syms_dev,
+            jnp.asarray(pids),
+            jnp.asarray(len_tables),
+            jnp.asarray(code_tables),
+            chunk_syms=chunk_bytes,
+            interpret=ops.interpret_mode(),
+        )
+    tracing.count("launches.bitpack")
     # The one device→host transfer: packed words + true bit counts together.
-    words_h, nbits_h = jax.device_get((words, nbits))
+    words_h, nbits_h = tracing.fetch((words, nbits))
     # uint32 words hold bit j of the chunk at word bit 31-j: big-endian byte
     # order recovers exactly the np.packbits stream the host encoder emits.
     stream = np.ascontiguousarray(words_h).byteswap().view(np.uint8).reshape(-1)
@@ -534,25 +526,28 @@ def _unpack_jobs(
     launch the per-chunk bit cursors (a metadata-sized transfer) feed the
     same integrity checks as ``huffman.decode_many``.
     """
-    import jax
     import jax.numpy as jnp
 
     from repro.kernels import huffdecode, ops
 
-    words, pids, counts, sizes = _pack_words(
-        jobs, entries_all, payloads_all, chunk_bytes
-    )
-    _count_payload_upload(words.nbytes)
-    syms, cursors = huffdecode.huffdecode_chunks_multi(
-        jnp.asarray(words),
-        jnp.asarray(pids),
-        jnp.asarray(counts),
-        jnp.asarray(luts),
-        chunk_bytes=chunk_bytes,
-        interpret=ops.interpret_mode(),
-    )
-    cursors_h = np.asarray(jax.device_get(cursors), dtype=np.int64)
-    _check_cursors(jobs, payloads_all, sizes, cursors_h)
+    with tracing.span("znn.codec.pack_words"):
+        words, pids, counts, sizes = _pack_words(
+            jobs, entries_all, payloads_all, chunk_bytes
+        )
+    tracing.count_payload_upload(words.nbytes)
+    with tracing.span("znn.codec.launch"):
+        syms, cursors = huffdecode.huffdecode_chunks_multi(
+            jnp.asarray(words),
+            jnp.asarray(pids),
+            jnp.asarray(counts),
+            jnp.asarray(luts),
+            chunk_bytes=chunk_bytes,
+            interpret=ops.interpret_mode(),
+        )
+    tracing.count("launches.huffdecode")
+    cursors_h = np.asarray(tracing.fetch(cursors), dtype=np.int64)
+    with tracing.span("znn.codec.cursor_check"):
+        _check_cursors(jobs, payloads_all, sizes, cursors_h)
     return syms
 
 
@@ -673,20 +668,24 @@ def decode_planes(
         for p in range(len(entries_all))
         for c in range(len(entries_all[p]))
     ]
-    _verify_payload_crcs(flat, entries_all, payloads_all, pool)
+    with tracing.span("znn.codec.chunk_crc"):
+        _verify_payload_crcs(flat, entries_all, payloads_all, pool)
     jobs = _huff_jobs(flat, entries_all, payloads_all, tables_all)
 
     huff_syms: dict = {}
     if jobs:
-        luts, _ = _stacked_luts(tables_all)
+        with tracing.span("znn.codec.luts"):
+            luts, _ = _stacked_luts(tables_all)
         per_launch = max(1, MAX_BATCH_BYTES // (2 * cb))
         for lo in range(0, len(jobs), per_launch):
             batch = jobs[lo : lo + per_launch]
             syms = _unpack_jobs(batch, entries_all, payloads_all, luts, cb)
             if not device_resident:
-                syms = np.asarray(syms)       # one transfer per launch window
-            for k, (p, ch) in enumerate(batch):
-                huff_syms[(p, ch)] = syms[k]
+                # one transfer per launch window
+                syms = np.asarray(tracing.fetch(syms))
+            with tracing.span("znn.codec.splice"):
+                for k, (p, ch) in enumerate(batch):
+                    huff_syms[(p, ch)] = syms[k]
 
     # Host work items: every non-HUFF chunk (identical decode + integrity
     # checks to PlaneCodec.decode_into).
@@ -694,8 +693,26 @@ def decode_planes(
         (p, c) for (p, c) in flat
         if entries_all[p][c].method != codec.Method.HUFF
     ]
-    other_chunks = _decode_other_chunks(others, entries_all, payloads_all, pool)
+    with tracing.span("znn.codec.host_chunks"):
+        other_chunks = _decode_other_chunks(
+            others, entries_all, payloads_all, pool
+        )
 
+    with tracing.span("znn.codec.splice"):
+        return _splice_planes(
+            entries_all, huff_syms, others, other_chunks, device_resident
+        )
+
+
+def _splice_planes(
+    entries_all: Sequence[Sequence[codec.ChunkEntry]],
+    huff_syms: Dict[Tuple[int, int], Any],
+    others: Sequence[Tuple[int, int]],
+    other_chunks: Dict[Tuple[int, int], np.ndarray],
+    device_resident: bool,
+) -> List[Any]:
+    """Assemble each plane from its kernel-decoded and host-decoded chunks
+    (the tail of :func:`decode_planes`)."""
     if not device_resident:
         planes: List[Any] = []
         for p in range(len(entries_all)):
@@ -730,7 +747,7 @@ def decode_planes(
             parts.append(piece)
             off += piece.size
         cat = np.concatenate(parts) if len(parts) > 1 else parts[0]
-        _count_payload_upload(cat.nbytes)
+        tracing.count_payload_upload(cat.nbytes)
         splice_dev = jnp.asarray(cat)
     planes = []
     for p in range(len(entries_all)):
@@ -789,7 +806,6 @@ class PayloadFeed:
         params: codec.CodecParams,
         pool=None,
     ):
-        import jax
         import jax.numpy as jnp
 
         from repro.kernels import huffdecode, ops
@@ -814,57 +830,87 @@ class PayloadFeed:
             for p in range(len(entries_all))
             for c in range(len(entries_all[p]))
         ]
-        _verify_payload_crcs(flat, entries_all, payloads_all, pool)
+        with tracing.span("znn.codec.chunk_crc"):
+            _verify_payload_crcs(flat, entries_all, payloads_all, pool)
         jobs = _huff_jobs(flat, entries_all, payloads_all, tables_all)
 
         self._luts = None
         self._windows: List[Tuple[Tuple[Tuple[int, int], ...], Any, Any, Any]] = []
         if jobs:
-            luts, _ = _stacked_luts(tables_all)
+            with tracing.span("znn.codec.luts"):
+                luts, _ = _stacked_luts(tables_all)
             self._luts = jnp.asarray(luts)
             per_launch = max(1, MAX_BATCH_BYTES // (2 * cb))
             for lo in range(0, len(jobs), per_launch):
                 batch = jobs[lo : lo + per_launch]
-                words, pids, counts, sizes = _pack_words(
-                    batch, entries_all, payloads_all, cb
-                )
-                _count_payload_upload(words.nbytes)
-                wd = jnp.asarray(words)
-                pd = jnp.asarray(pids)
-                cd = jnp.asarray(counts)
-                # Warmup launch: compiles the dispatch and runs the cursor /
-                # pad-bit integrity checks once for the feed's lifetime.
-                _syms, cursors = huffdecode.huffdecode_chunks_multi(
-                    wd, pd, cd, self._luts,
-                    chunk_bytes=cb,
-                    interpret=self._interpret,
-                )
-                cursors_h = np.asarray(jax.device_get(cursors), dtype=np.int64)
-                _check_cursors(batch, payloads_all, sizes, cursors_h)
+                with tracing.span("znn.codec.pack_words"):
+                    words, pids, counts, sizes = _pack_words(
+                        batch, entries_all, payloads_all, cb
+                    )
+                tracing.count_payload_upload(words.nbytes)
+                with tracing.span("znn.codec.launch"):
+                    wd = jnp.asarray(words)
+                    pd = jnp.asarray(pids)
+                    cd = jnp.asarray(counts)
+                    # Warmup launch: compiles the dispatch and runs the
+                    # cursor / pad-bit integrity checks once for the feed's
+                    # lifetime.
+                    _syms, cursors = huffdecode.huffdecode_chunks_multi(
+                        wd, pd, cd, self._luts,
+                        chunk_bytes=cb,
+                        interpret=self._interpret,
+                    )
+                tracing.count("launches.huffdecode")
+                cursors_h = np.asarray(tracing.fetch(cursors), dtype=np.int64)
+                with tracing.span("znn.codec.cursor_check"):
+                    _check_cursors(batch, payloads_all, sizes, cursors_h)
                 self._windows.append((tuple(batch), wd, pd, cd))
 
         others = [
             (p, c) for (p, c) in flat
             if entries_all[p][c].method != codec.Method.HUFF
         ]
-        other_chunks = _decode_other_chunks(others, entries_all, payloads_all, pool)
+        with tracing.span("znn.codec.host_chunks"):
+            other_chunks = _decode_other_chunks(
+                others, entries_all, payloads_all, pool
+            )
         self._splice = None
         self._splice_off: Dict[Tuple[int, int], Tuple[int, int]] = {}
         if others:
-            off = 0
-            parts = []
-            for key in others:
-                piece = other_chunks[key]
-                self._splice_off[key] = (off, off + piece.size)
-                parts.append(piece)
-                off += piece.size
-            cat = np.concatenate(parts) if len(parts) > 1 else parts[0]
-            _count_payload_upload(cat.nbytes)
-            self._splice = jnp.asarray(cat)
+            with tracing.span("znn.codec.splice"):
+                off = 0
+                parts = []
+                for key in others:
+                    piece = other_chunks[key]
+                    self._splice_off[key] = (off, off + piece.size)
+                    parts.append(piece)
+                    off += piece.size
+                cat = np.concatenate(parts) if len(parts) > 1 else parts[0]
+                tracing.count_payload_upload(cat.nbytes)
+                self._splice = jnp.asarray(cat)
+        self.dispatches = self._count_dispatches()
 
     @property
     def n_planes(self) -> int:
         return len(self._meta)
+
+    def _count_dispatches(self) -> int:
+        """Eager device ops one :meth:`decode` issues, from its pieces: a
+        launch per window, a row index (slice + squeeze) per HUFF chunk and
+        a trim where the chunk is short, a slice per spliced chunk unless it
+        is the whole splice buffer, a concatenate per plane of several
+        pieces."""
+        n = len(self._windows)
+        splice_size = 0 if self._splice is None else int(self._splice.size)
+        for p, metas in enumerate(self._meta):
+            for c, (m, raw_len) in enumerate(metas):
+                if m == codec.Method.HUFF:
+                    n += 2 + (raw_len < self.chunk_bytes)
+                else:
+                    lo, hi = self._splice_off[(p, c)]
+                    n += (lo, hi) != (0, splice_size)
+            n += len(metas) > 1
+        return n
 
     @property
     def device_bytes(self) -> int:
@@ -881,6 +927,13 @@ class PayloadFeed:
         the same parsed stream; no host payload bytes are touched and no
         payload-sized host→device transfer occurs.
         """
+        with tracing.span("znn.feed.decode"):
+            planes = self._decode()
+        tracing.count("launches.huffdecode", len(self._windows))
+        tracing.count("feed_dispatches", self.dispatches)
+        return planes
+
+    def _decode(self) -> List[Any]:
         import jax.numpy as jnp
 
         from repro.kernels import huffdecode

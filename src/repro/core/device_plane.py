@@ -51,7 +51,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import bitlayout, codec
+from . import bitlayout, codec, tracing
 
 __all__ = [
     "BACKENDS",
@@ -292,7 +292,6 @@ def produce_planes_batched(
         )
         return out
 
-    import jax
     import jax.numpy as jnp
 
     from repro.kernels import fused_plane, ops
@@ -346,13 +345,15 @@ def produce_planes_batched(
         else None
     )
 
-    planes2d, hists_dev = fused_plane.plane_producer(
-        x2, base2, itemsize=layout.itemsize, chunk_elems=cb,
-        interpret=ops.interpret_mode(),
-    )
+    with tracing.span("znn.codec.launch"):
+        planes2d, hists_dev = fused_plane.plane_producer(
+            x2, base2, itemsize=layout.itemsize, chunk_elems=cb,
+            interpret=ops.interpret_mode(),
+        )
+    tracing.count("launches.plane_producer")
     # The one device→host transfer of the whole batch: planed uint8 buffers
     # + probe histograms together.
-    planes_host, hists_host = jax.device_get((planes2d, hists_dev))
+    planes_host, hists_host = tracing.fetch((planes2d, hists_dev))
     flat = [np.asarray(p).reshape(-1) for p in planes_host]
     flat_dev = [p.reshape(-1) for p in planes2d]   # stays resident on device
     hists = np.asarray(hists_host).astype(np.int64)  # (chunks, n_planes, 256)

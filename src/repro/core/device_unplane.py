@@ -59,7 +59,7 @@ from typing import Any, List, Optional, Sequence
 
 import numpy as np
 
-from . import bitlayout
+from . import bitlayout, tracing
 from .device_plane import (
     MAX_BATCH_BYTES,
     _dev_elems,
@@ -75,6 +75,7 @@ __all__ = [
     "consume_planes",
     "consume_planes_batched",
     "consume_payloads",
+    "resident_dispatches",
 ]
 
 BACKENDS = ("host", "device", "auto")
@@ -234,7 +235,38 @@ def consume_planes_batched(
         )
         return out
 
-    import jax
+    with tracing.span("znn.codec.unplane"):
+        return _consume(planes_list, layout, sizes, bases, device_resident)
+
+
+def _tail(total: int, layout: bitlayout.BitLayout) -> int:
+    """Zero elements that pad ``total`` to the kernel's row-block alignment."""
+    from repro.kernels import fused_unplane
+
+    align = (
+        fused_unplane.ALIGN_ELEMS_U16
+        if layout.itemsize == 2
+        else fused_unplane.ALIGN_ELEMS_U32
+    )
+    return -total % align
+
+
+def resident_dispatches(size: int, layout: bitlayout.BitLayout) -> int:
+    """Eager device ops of :func:`consume_planes` on one leaf of ``size``
+    elements whose planes are device arrays, with ``device_resident=True``:
+    per plane a reshape, plus a pad and a concatenate where the leaf needs
+    a tail; the launch; the flattening reshape; the trim of the tail."""
+    tail = _tail(size, layout) > 0
+    return layout.n_planes * (1 + 2 * tail) + 2 + tail
+
+
+def _consume(
+    planes_list: Sequence[Sequence[Any]],
+    layout: bitlayout.BitLayout,
+    sizes: List[int],
+    bases: Optional[Sequence[Any]],
+    device_resident: bool,
+) -> List[Any]:
     import jax.numpy as jnp
 
     from repro.kernels import fused_unplane, ops
@@ -242,12 +274,7 @@ def consume_planes_batched(
     total = sum(sizes)
     if total == 0:                               # every leaf empty: no dispatch
         return [np.empty(0, np.uint8) for _ in sizes]
-    align = (
-        fused_unplane.ALIGN_ELEMS_U16
-        if layout.itemsize == 2
-        else fused_unplane.ALIGN_ELEMS_U32
-    )
-    tail = -total % align
+    tail = _tail(total, layout)
 
     # One upload per plane index: the concatenation of every leaf's plane.
     # Device-resident planes (the fused entropy decoder's output) stay on
@@ -297,6 +324,7 @@ def consume_planes_batched(
         tuple(dev_planes), base2, itemsize=layout.itemsize,
         interpret=ops.interpret_mode(),
     )
+    tracing.count("launches.plane_consumer")
     if device_resident:
         # Zero-bounce: per-leaf element slices stay on device for the
         # caller (bitcast to the real dtype / device_put re-shard there).
@@ -308,7 +336,7 @@ def consume_planes_batched(
             off += s
         return out
     # The one device→host transfer: reconstructed elements for the batch.
-    elems = np.asarray(jax.device_get(x2)).reshape(-1)
+    elems = np.asarray(tracing.fetch(x2)).reshape(-1)
 
     out = []
     off = 0

@@ -76,7 +76,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from . import bitlayout, codec, container, engine
+from . import bitlayout, codec, container, engine, tracing
 from .engine import (             # noqa: F401  (re-exported streaming API)
     CompressWriter,
     DecompressReader,
@@ -371,16 +371,19 @@ def _entropy_decode(
     kernel-decoded symbols stay on device exactly when the fused consumer
     will eat them in place.
     """
-    meta, mv = container.unpack_stream(blob)
-    layout = bitlayout.layout_by_name(meta.layout_name)
-    params = codec.CodecParams(chunk_bytes=meta.chunk_bytes, backend=config.backend)
-    payload_lists = [
-        [
-            container.payload_view(meta, mv, p, c)
-            for c in range(len(meta.entries[p]))
+    with tracing.span("znn.codec.parse"):
+        meta, mv = container.unpack_stream(blob)
+        layout = bitlayout.layout_by_name(meta.layout_name)
+        params = codec.CodecParams(
+            chunk_bytes=meta.chunk_bytes, backend=config.backend
+        )
+        payload_lists = [
+            [
+                container.payload_view(meta, mv, p, c)
+                for c in range(len(meta.entries[p]))
+            ]
+            for p in range(meta.n_planes)
         ]
-        for p in range(meta.n_planes)
-    ]
     use_device = any(
         e.method == codec.Method.HUFF for pe in meta.entries for e in pe
     ) and _resolve_decode_entropy(
@@ -637,6 +640,20 @@ class ArrayFeed:
         """Resident HBM footprint of the compressed payload buffers."""
         return self._feed.device_bytes
 
+    @property
+    def dispatches(self) -> int:
+        """Eager device ops one :meth:`decode` issues: the payload feed's,
+        the plane consumer's, and the bitcast and reshape to the leaf."""
+        from . import device_unplane
+
+        n = int(np.prod(self.shape, dtype=np.int64))
+        return (
+            self._feed.dispatches
+            + device_unplane.resident_dispatches(n, self._layout)
+            + 1
+            + (tuple(self.shape) != (n,))
+        )
+
     def decode(self) -> Any:
         """The restored leaf as a device-resident ``jax.Array``."""
         import jax
@@ -648,9 +665,11 @@ class ArrayFeed:
         elems = device_unplane.consume_planes(
             planes, self._layout, device_resident=True
         )
-        return jax.lax.bitcast_convert_type(
+        out = jax.lax.bitcast_convert_type(
             elems, jnp.dtype(_np_dtype(self.dtype))
         ).reshape(self.shape)
+        tracing.count("feed_dispatches", self.dispatches - self._feed.dispatches)
+        return out
 
 
 def build_array_feed(
@@ -826,90 +845,91 @@ def decompress_pytree(
     threads, backend, entropy_backend, device_resident = (
         opts.threads, opts.backend, opts.entropy_backend, opts.device_resident,
     )
-    cts: List[CompressedTensor] = manifest["leaves"]
-    arrays: List[Optional[Any]] = [None] * len(cts)
+    with tracing.span("znn.codec.decode_tree"):
+        cts: List[CompressedTensor] = manifest["leaves"]
+        arrays: List[Optional[Any]] = [None] * len(cts)
 
-    requested = config.plane_backend if backend is None else backend
-    if requested != "host" and cts:
-        from . import device_plane, device_unplane
+        requested = config.plane_backend if backend is None else backend
+        if requested != "host" and cts:
+            from . import device_plane, device_unplane
 
-        pool = engine.get_pool(config.threads if threads is None else threads)
-        groups: Dict[str, List[int]] = {}
-        for i, ct in enumerate(cts):
-            layout = bitlayout.LAYOUTS.get(ct.dtype)
-            if (
-                layout is not None
-                and device_unplane.resolve(requested, layout) == "device"
-            ):
-                groups.setdefault(layout.name, []).append(i)
-        # Entropy-decode and dispatch one MAX_BATCH_BYTES window at a time:
-        # peak host memory is one window of planes + the output arrays, not
-        # every leaf's planes at once — the O(window) story of the file API
-        # applied to tree restores.
-        for name, idxs in groups.items():
-            layout = bitlayout.layout_by_name(name)
-            win_idx: List[int] = []
-            win_planes: List[List[np.ndarray]] = []
-            acc = 0
-
-            def flush():
-                if device_resident:
-                    elems = device_unplane.consume_planes_batched(
-                        win_planes, layout, device_resident=True
-                    )
-                    for i, el in zip(win_idx, elems):
-                        arrays[i] = jax.lax.bitcast_convert_type(
-                            el, jnp.dtype(_np_dtype(cts[i].dtype))
-                        ).reshape(cts[i].shape)
-                else:
-                    raws = device_unplane.consume_planes_batched(
-                        win_planes, layout
-                    )
-                    for i, raw in zip(win_idx, raws):
-                        arrays[i] = (
-                            np.frombuffer(raw.tobytes(), dtype=_np_dtype(cts[i].dtype))
-                            .reshape(cts[i].shape)
-                            .copy()
-                        )
-                win_idx.clear()
-                win_planes.clear()
-
-            for i in idxs:
-                blob_layout, planes, tail = _entropy_decode(
-                    cts[i].blob, config, pool,
-                    entropy_backend=entropy_backend, backend=backend,
-                )
+            pool = engine.get_pool(config.threads if threads is None else threads)
+            groups: Dict[str, List[int]] = {}
+            for i, ct in enumerate(cts):
+                layout = bitlayout.LAYOUTS.get(ct.dtype)
                 if (
-                    tail
-                    or blob_layout.name != layout.name
-                    or not planes
-                    or not planes[0].size
+                    layout is not None
+                    and device_unplane.resolve(requested, layout) == "device"
                 ):
-                    continue                   # edge cases ride the host path
-                win_idx.append(i)
-                win_planes.append(planes)
-                acc += planes[0].size * layout.itemsize
-                if acc >= device_plane.MAX_BATCH_BYTES:
-                    flush()
-                    acc = 0
-            if win_idx:
-                flush()
+                    groups.setdefault(layout.name, []).append(i)
+            # Entropy-decode and dispatch one MAX_BATCH_BYTES window at a time:
+            # peak host memory is one window of planes + the output arrays, not
+            # every leaf's planes at once — the O(window) story of the file API
+            # applied to tree restores.
+            for name, idxs in groups.items():
+                layout = bitlayout.layout_by_name(name)
+                win_idx: List[int] = []
+                win_planes: List[List[np.ndarray]] = []
+                acc = 0
 
-    for i, ct in enumerate(cts):
-        if arrays[i] is None:
-            # Leaves the device batch skipped decode host-planed, but a
-            # 'device'/'auto' request still covers their entropy stage.
-            arrays[i] = decompress_array(
-                ct, config,
-                options=CodecOptions(
-                    threads=threads, backend="host",
-                    entropy_backend=(
-                        entropy_backend if entropy_backend is not None else backend
+                def flush():
+                    if device_resident:
+                        elems = device_unplane.consume_planes_batched(
+                            win_planes, layout, device_resident=True
+                        )
+                        for i, el in zip(win_idx, elems):
+                            arrays[i] = jax.lax.bitcast_convert_type(
+                                el, jnp.dtype(_np_dtype(cts[i].dtype))
+                            ).reshape(cts[i].shape)
+                    else:
+                        raws = device_unplane.consume_planes_batched(
+                            win_planes, layout
+                        )
+                        for i, raw in zip(win_idx, raws):
+                            arrays[i] = (
+                                np.frombuffer(raw.tobytes(), dtype=_np_dtype(cts[i].dtype))
+                                .reshape(cts[i].shape)
+                                .copy()
+                            )
+                    win_idx.clear()
+                    win_planes.clear()
+
+                for i in idxs:
+                    blob_layout, planes, tail = _entropy_decode(
+                        cts[i].blob, config, pool,
+                        entropy_backend=entropy_backend, backend=backend,
+                    )
+                    if (
+                        tail
+                        or blob_layout.name != layout.name
+                        or not planes
+                        or not planes[0].size
+                    ):
+                        continue                   # edge cases ride the host path
+                    win_idx.append(i)
+                    win_planes.append(planes)
+                    acc += planes[0].size * layout.itemsize
+                    if acc >= device_plane.MAX_BATCH_BYTES:
+                        flush()
+                        acc = 0
+                if win_idx:
+                    flush()
+
+        for i, ct in enumerate(cts):
+            if arrays[i] is None:
+                # Leaves the device batch skipped decode host-planed, but a
+                # 'device'/'auto' request still covers their entropy stage.
+                arrays[i] = decompress_array(
+                    ct, config,
+                    options=CodecOptions(
+                        threads=threads, backend="host",
+                        entropy_backend=(
+                            entropy_backend if entropy_backend is not None else backend
+                        ),
+                        device_resident=device_resident,
                     ),
-                    device_resident=device_resident,
-                ),
-            )
-    return jax.tree_util.tree_unflatten(manifest["treedef"], arrays)
+                )
+        return jax.tree_util.tree_unflatten(manifest["treedef"], arrays)
 
 
 # ---------------------------------------------------------------------------

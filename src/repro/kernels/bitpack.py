@@ -156,6 +156,7 @@ def bitpack_encode_chunks_multi(
             jax.ShapeDtypeStruct((c * n,), jnp.int32),
             jax.ShapeDtypeStruct((c,), jnp.int32),
         ],
+        name="bitpack_encode_chunks_multi",
         interpret=interpret,
     )(
         plane_ids.astype(jnp.int32),

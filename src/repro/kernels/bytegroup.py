@@ -69,6 +69,7 @@ def bytegroup_bf16_2d(x: jax.Array, *, interpret: bool = True):
         in_specs=[_spec(BF16_ROWS)],
         out_specs=[_spec(BF16_ROWS)] * 2,
         out_shape=[jax.ShapeDtypeStruct((m, LANES), jnp.uint8)] * 2,
+        name="bytegroup_bf16_2d",
         interpret=interpret,
     )(x)
 
@@ -82,6 +83,7 @@ def ungroup_bf16_2d(exp: jax.Array, frac: jax.Array, *, interpret: bool = True):
         in_specs=[_spec(BF16_ROWS)] * 2,
         out_specs=_spec(BF16_ROWS),
         out_shape=jax.ShapeDtypeStruct((m, LANES), jnp.uint16),
+        name="ungroup_bf16_2d",
         interpret=interpret,
     )(exp, frac)
 
@@ -96,6 +98,7 @@ def bytegroup_fp32_2d(x: jax.Array, *, interpret: bool = True):
         in_specs=[_spec(FP32_ROWS)],
         out_specs=[_spec(FP32_ROWS)] * 4,
         out_shape=[jax.ShapeDtypeStruct((m, LANES), jnp.uint8)] * 4,
+        name="bytegroup_fp32_2d",
         interpret=interpret,
     )(x)
 
@@ -109,5 +112,6 @@ def ungroup_fp32_2d(p0, p1, p2, p3, *, interpret: bool = True):
         in_specs=[_spec(FP32_ROWS)] * 4,
         out_specs=_spec(FP32_ROWS),
         out_shape=jax.ShapeDtypeStruct((m, LANES), jnp.uint32),
+        name="ungroup_fp32_2d",
         interpret=interpret,
     )(p0, p1, p2, p3)

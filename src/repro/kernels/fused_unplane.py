@@ -116,5 +116,6 @@ def plane_consumer(
         in_specs=[_spec(rows)] * len(operands),
         out_specs=_spec(rows),
         out_shape=jax.ShapeDtypeStruct((m, LANES), out_dtype),
+        name="plane_consumer",
         interpret=interpret,
     )(*operands)

@@ -75,6 +75,7 @@ def chunk_histogram_2d(
         out_specs=pl.BlockSpec((1, 1, 256), lambda i, j: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_chunks, 1, 256), jnp.int32),
         scratch_shapes=[pltpu.VMEM((256, LANES), jnp.int32)],
+        name="chunk_histogram_2d",
         interpret=interpret,
     )(x)
     return out.reshape(n_chunks, 256)

@@ -162,6 +162,7 @@ def huffdecode_chunks_multi(
             jax.ShapeDtypeStruct((c * cw,), jnp.int32),
             jax.ShapeDtypeStruct((c,), jnp.int32),
         ],
+        name="huffdecode_chunks_multi",
         interpret=interpret,
     )(
         plane_ids.astype(jnp.int32),

@@ -51,6 +51,7 @@ def xor_elems_2d(a: jax.Array, b: jax.Array, *, interpret: bool = True):
         in_specs=[pl.BlockSpec((XOR_ROWS, LANES), lambda i: (i, 0))] * 2,
         out_specs=pl.BlockSpec((XOR_ROWS, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
+        name="xor_elems_2d",
         interpret=interpret,
     )(a, b)
 
@@ -71,5 +72,6 @@ def xor_delta_2d(a: jax.Array, b: jax.Array, *, interpret: bool = True):
             jax.ShapeDtypeStruct((m, LANES), jnp.uint32),
             jax.ShapeDtypeStruct((1,), jnp.int32),
         ],
+        name="xor_delta_2d",
         interpret=interpret,
     )(a, b)

@@ -17,6 +17,7 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
+from repro.core import tracing
 from repro.models.model import Model
 
 PyTree = Any
@@ -344,9 +345,14 @@ def make_compressed_serve_step(
     def _decode(n: int):
         j, t = divmod(n, tiles)
         key, i, _ = plan[j]
-        if tiles == 1:
-            return store.decode_layer(key, i)
-        return store.decode_layer_tile(key, i, t, tiles)
+        with tracing.span("znn.ring.decode"):
+            if tiles == 1:
+                return store.decode_layer(key, i)
+            return store.decode_layer_tile(key, i, t, tiles)
+
+    def _job(n: int, op):
+        with tracing.joined(op):
+            return _decode(n)
 
     def _release(key: str, i: int) -> None:
         if tiles == 1:
@@ -356,9 +362,14 @@ def make_compressed_serve_step(
                 store.release_tile(key, i, t, tiles)
 
     def serve_step(state, tokens):
+        with tracing.operation("znn.ring.step"):
+            return _step(state, tokens)
+
+    def _step(state, tokens):
         pos = state["pos"]
         x = _decode_front(cfg, store.static, tokens, pos)
         new_state = dict(state)
+        op = tracing.current_op()
 
         inflight: list = []
         nxt = 0
@@ -373,16 +384,17 @@ def make_compressed_serve_step(
                 and nxt < n_jobs
                 and len(inflight) < depth
             ):
-                inflight.append(executor.submit(_decode, nxt))
+                inflight.append(executor.submit(_job, nxt, op))
                 nxt += 1
 
         def next_job(n: int):
             nonlocal nxt
-            if inflight:
-                out = inflight.pop(0).result()
-            else:
-                out = _decode(n)
-                nxt = n + 1
+            with tracing.span("znn.ring.wait"):
+                if inflight:
+                    out = inflight.pop(0).result()
+                else:
+                    out = _decode(n)
+                    nxt = n + 1
             pump()
             return out
 
@@ -405,26 +417,30 @@ def make_compressed_serve_step(
             outs_s, outs_c = [], []
             for j, (key, i, kind) in enumerate(plan):
                 lp = layer_params(j)
-                x, (st, cv) = kinds[kind](
-                    lp, x, state["ssm_state"][j], state["ssm_conv"][j], pos
-                )
-                _release(key, i)
+                with tracing.span("znn.ring.layer"):
+                    x, (st, cv) = kinds[kind](
+                        lp, x, state["ssm_state"][j], state["ssm_conv"][j], pos
+                    )
+                    _release(key, i)
                 outs_s.append(st)
                 outs_c.append(cv)
-            new_state["ssm_state"] = jnp.stack(outs_s)
-            new_state["ssm_conv"] = jnp.stack(outs_c)
+            with tracing.span("znn.ring.tail"):
+                new_state["ssm_state"] = jnp.stack(outs_s)
+                new_state["ssm_conv"] = jnp.stack(outs_c)
         elif kv_store is not None:
             outs0, outs1 = [], []
             for j, (key, i, kind) in enumerate(plan):
                 lp = layer_params(j)
-                c0j, c1j = kv_store.layer_caches(j)
-                x, (u0, u1) = kinds[kind](lp, x, c0j, c1j, pos)
-                _release(key, i)
+                with tracing.span("znn.ring.layer"):
+                    c0j, c1j = kv_store.layer_caches(j)
+                    x, (u0, u1) = kinds[kind](lp, x, c0j, c1j, pos)
+                    _release(key, i)
                 outs0.append(u0)
                 outs1.append(u1)
             # single post-loop cache write, exactly as decode_step — into
             # the tiered store's hot buffer instead of the state dict
-            kv_store.append(jnp.stack(outs0), jnp.stack(outs1))
+            with tracing.span("znn.ring.tail"):
+                kv_store.append(jnp.stack(outs0), jnp.stack(outs1))
         else:
             c0, c1 = (
                 (state["mla_ckv"], state["mla_kr"])
@@ -436,21 +452,24 @@ def make_compressed_serve_step(
             outs0, outs1 = [], []
             for j, (key, i, kind) in enumerate(plan):
                 lp = layer_params(j)
-                x, (u0, u1) = kinds[kind](lp, x, c0[j], c1[j], pos)
-                _release(key, i)
+                with tracing.span("znn.ring.layer"):
+                    x, (u0, u1) = kinds[kind](lp, x, c0[j], c1[j], pos)
+                    _release(key, i)
                 outs0.append(u0)
                 outs1.append(u1)
             # single slot write for all layers, exactly as decode_step
-            n0, n1 = jnp.stack(outs0), jnp.stack(outs1)
-            if cfg.mla:
-                new_state["mla_ckv"] = _slot_write(c0, n0, slot)
-                new_state["mla_kr"] = _slot_write(c1, n1, slot)
-            else:
-                new_state["kv_k"] = _slot_write(c0, n0, slot)
-                new_state["kv_v"] = _slot_write(c1, n1, slot)
+            with tracing.span("znn.ring.tail"):
+                n0, n1 = jnp.stack(outs0), jnp.stack(outs1)
+                if cfg.mla:
+                    new_state["mla_ckv"] = _slot_write(c0, n0, slot)
+                    new_state["mla_kr"] = _slot_write(c1, n1, slot)
+                else:
+                    new_state["kv_k"] = _slot_write(c0, n0, slot)
+                    new_state["kv_v"] = _slot_write(c1, n1, slot)
 
-        logits = _decode_tail(cfg, store.static, x)
-        new_state["pos"] = pos + 1
+        with tracing.span("znn.ring.tail"):
+            logits = _decode_tail(cfg, store.static, x)
+            new_state["pos"] = pos + 1
         return logits, new_state
 
     serve_step.store = store
