@@ -48,8 +48,10 @@ once; decoded symbols can stay device-resident
 (``device_resident=True``) so the fused un-plane consumer never re-uploads
 them — the zero-bounce restore path.  CRC verification, the
 ``decode_many``-equivalent bit-cursor + pad-bit integrity checks, and
-``ZERO``/``STORE``/``ZLIB`` chunk decode stay host-side; those spliced
-chunks ride one additional upload on the device-resident path.  The decode
+``ZERO``/``STORE``/``ZLIB`` chunk decode stay host-side; those chunks ride
+one additional upload on the device-resident path, as rows that one
+compiled assembly (:func:`_assemble`) gathers with the kernel's symbol
+rows into planes.  The decode
 envelope (:func:`supports_decode`) keys off the *container's* chunk
 geometry, not the config's coder: the stream records which chunks are
 ``HUFF``, so any blob the canonical coder produced decodes on device
@@ -659,7 +661,8 @@ def decode_planes(
     :func:`repro.core.codec.decompress_plane` byte-for-byte — numpy by
     default (one device→host transfer of decoded symbols), or
     device-resident ``jax.Array`` planes with ``device_resident=True``
-    (spliced on device; no symbol download), ready for
+    (put together on device by one compiled assembly, :func:`_assemble`;
+    no symbol download), ready for
     :func:`repro.core.device_unplane.consume_planes` to consume in place.
     """
     cb = params.chunk_bytes
@@ -671,7 +674,14 @@ def decode_planes(
     with tracing.span("znn.codec.chunk_crc"):
         _verify_payload_crcs(flat, entries_all, payloads_all, pool)
     jobs = _huff_jobs(flat, entries_all, payloads_all, tables_all)
+    others = [
+        (p, c) for (p, c) in flat
+        if entries_all[p][c].method != codec.Method.HUFF
+    ]
+    if device_resident:
+        index, lengths = _assembly_plan(entries_all, jobs, others, cb)
 
+    windows: List[Any] = []
     huff_syms: dict = {}
     if jobs:
         with tracing.span("znn.codec.luts"):
@@ -680,91 +690,156 @@ def decode_planes(
         for lo in range(0, len(jobs), per_launch):
             batch = jobs[lo : lo + per_launch]
             syms = _unpack_jobs(batch, entries_all, payloads_all, luts, cb)
-            if not device_resident:
-                # one transfer per launch window
-                syms = np.asarray(tracing.fetch(syms))
+            if device_resident:
+                windows.append(syms)
+                continue
+            # one transfer per launch window
+            syms = np.asarray(tracing.fetch(syms))
             with tracing.span("znn.codec.splice"):
                 for k, (p, ch) in enumerate(batch):
                     huff_syms[(p, ch)] = syms[k]
 
     # Host work items: every non-HUFF chunk (identical decode + integrity
     # checks to PlaneCodec.decode_into).
-    others = [
-        (p, c) for (p, c) in flat
-        if entries_all[p][c].method != codec.Method.HUFF
-    ]
     with tracing.span("znn.codec.host_chunks"):
         other_chunks = _decode_other_chunks(
             others, entries_all, payloads_all, pool
         )
 
     with tracing.span("znn.codec.splice"):
-        return _splice_planes(
-            entries_all, huff_syms, others, other_chunks, device_resident
-        )
+        if not device_resident:
+            return _splice_host(entries_all, huff_syms, other_chunks)
+        import jax.numpy as jnp
+
+        if others:
+            rows = _other_rows(others, other_chunks, cb)
+            tracing.count_payload_upload(rows.nbytes)
+            windows.append(jnp.asarray(rows))
+        return _assemble(windows, index, lengths)
 
 
-def _splice_planes(
+def _splice_host(
     entries_all: Sequence[Sequence[codec.ChunkEntry]],
-    huff_syms: Dict[Tuple[int, int], Any],
-    others: Sequence[Tuple[int, int]],
+    huff_syms: Dict[Tuple[int, int], np.ndarray],
     other_chunks: Dict[Tuple[int, int], np.ndarray],
-    device_resident: bool,
-) -> List[Any]:
-    """Assemble each plane from its kernel-decoded and host-decoded chunks
-    (the tail of :func:`decode_planes`)."""
-    if not device_resident:
-        planes: List[Any] = []
-        for p in range(len(entries_all)):
-            entries = entries_all[p]
-            total = sum(e.raw_len for e in entries)
-            out = np.empty(total, dtype=np.uint8)
-            off = 0
-            for c, e in enumerate(entries):
-                piece = (
-                    huff_syms[(p, c)][: e.raw_len]
-                    if e.method == codec.Method.HUFF
-                    else other_chunks[(p, c)]
-                )
-                out[off : off + e.raw_len] = piece
-                off += e.raw_len
-            planes.append(out)
-        return planes
-
-    import jax.numpy as jnp
-
-    # Device splice: all host-decoded (non-HUFF) chunk bytes ride ONE
-    # upload; per-chunk device slices interleave with the kernel-decoded
-    # symbol rows so each plane assembles without a host bounce.
-    splice_dev = None
-    splice_off: dict = {}
-    if others:
-        off = 0
-        parts = []
-        for key in others:
-            piece = other_chunks[key]
-            splice_off[key] = (off, off + piece.size)
-            parts.append(piece)
-            off += piece.size
-        cat = np.concatenate(parts) if len(parts) > 1 else parts[0]
-        tracing.count_payload_upload(cat.nbytes)
-        splice_dev = jnp.asarray(cat)
-    planes = []
+) -> List[np.ndarray]:
+    """Put each plane together on the host from its kernel-decoded and
+    host-decoded chunks (the tail of :func:`decode_planes` with
+    ``device_resident=False``)."""
+    planes: List[np.ndarray] = []
     for p in range(len(entries_all)):
         entries = entries_all[p]
-        pieces = []
+        total = sum(e.raw_len for e in entries)
+        out = np.empty(total, dtype=np.uint8)
+        off = 0
         for c, e in enumerate(entries):
-            if e.method == codec.Method.HUFF:
-                pieces.append(huff_syms[(p, c)][: e.raw_len])
-            else:
-                lo, hi = splice_off[(p, c)]
-                pieces.append(splice_dev[lo:hi])
-        if not pieces:
-            planes.append(np.empty(0, dtype=np.uint8))
-        elif len(pieces) == 1:
-            planes.append(pieces[0])
-        else:
-            planes.append(jnp.concatenate(pieces))
+            piece = (
+                huff_syms[(p, c)][: e.raw_len]
+                if e.method == codec.Method.HUFF
+                else other_chunks[(p, c)]
+            )
+            out[off : off + e.raw_len] = piece
+            off += e.raw_len
+        planes.append(out)
+    return planes
+
+
+# ---------------------------------------------------------------------------
+# plane assembly on the device
+# ---------------------------------------------------------------------------
+#
+# A device-resident decode ends with its planes' chunks in rows: the launch
+# windows' ``(c, chunk_bytes)`` symbol arrays, one row per HUFF chunk in
+# ``jobs`` order, then the host-decoded chunks' ``(n_other, chunk_bytes)``
+# rows in ``others`` order.  The container cuts every plane at
+# ``chunk_bytes`` strides, so a plane is its chunks' rows in order,
+# flattened and cut to its length: one gather per plane, all of a stream's
+# planes in one compiled dispatch.  The compile key is shapes only (the
+# sources' row counts and the planes' lengths); which row goes where is a
+# runtime argument, so streams of one shape never recompile because a
+# different chunk fell back to STORE.
+
+
+def _assembly_plan(
+    entries_all: Sequence[Sequence[codec.ChunkEntry]],
+    jobs: Sequence[Tuple[int, int]],
+    others: Sequence[Tuple[int, int]],
+    chunk_bytes: int,
+) -> Tuple[np.ndarray, Tuple[int, ...]]:
+    """Where each chunk's bytes lie in the joined rows, from the chunk
+    table alone.
+
+    Returns the int32 row of every chunk, plane-major, and the planes'
+    lengths.  A plane whose chunks are not cut at ``chunk_bytes`` strides
+    (a chunk longer than a row, an empty chunk, a short chunk before the
+    plane's last) is corrupt.
+    """
+    row = {key: i for i, key in enumerate(jobs)}
+    row.update((key, len(jobs) + i) for i, key in enumerate(others))
+    index = np.empty(len(row), dtype=np.int32)
+    lengths = []
+    k = 0
+    for p, entries in enumerate(entries_all):
+        last = len(entries) - 1
+        for c, e in enumerate(entries):
+            n = e.raw_len
+            if not 0 < n <= chunk_bytes or (c < last and n != chunk_bytes):
+                raise IOError(
+                    "corrupt chunk table: plane not cut at chunk_bytes "
+                    f"strides (plane {p}, chunk {c}, {n} bytes)"
+                )
+            index[k] = row[(p, c)]
+            k += 1
+        lengths.append(sum(e.raw_len for e in entries))
+    return index, tuple(lengths)
+
+
+def _other_rows(
+    others: Sequence[Tuple[int, int]],
+    other_chunks: Dict[Tuple[int, int], np.ndarray],
+    chunk_bytes: int,
+) -> np.ndarray:
+    """The host-decoded chunks as ``(n_other, chunk_bytes)`` rows, a
+    plane's short last chunk zero-padded."""
+    rows = np.zeros((len(others), chunk_bytes), dtype=np.uint8)
+    for k, key in enumerate(others):
+        piece = other_chunks[key]
+        rows[k, : piece.size] = piece
+    return rows
+
+
+@functools.cache
+def _assembler():
+    """The jitted assembly; ``lengths`` is its one static argument."""
+    import jax
+    import jax.numpy as jnp
+
+    def assemble(sources, index, lengths):
+        rows = sources[0] if len(sources) == 1 else jnp.concatenate(sources)
+        cb = rows.shape[1]
+        planes, lo = [], 0
+        for n in lengths:
+            k = -(-n // cb)
+            planes.append(rows[index[lo : lo + k]].reshape(-1)[:n])
+            lo += k
+        return planes
+
+    return jax.jit(assemble, static_argnums=2)
+
+
+def _assemble(
+    sources: Sequence[Any], index: Any, lengths: Tuple[int, ...]
+) -> List[Any]:
+    """Device planes of ``lengths`` bytes from the rows of ``sources``
+    (launch windows, then the host-decoded rows), placed by ``index`` from
+    :func:`_assembly_plan`: one compiled dispatch, or none for a stream
+    without chunks."""
+    n = int(index.shape[0])
+    if not n:
+        return [np.empty(0, dtype=np.uint8) for _ in lengths]
+    planes = _assembler()(tuple(sources), index, lengths)
+    tracing.count("assemblies")
+    tracing.count("assembled_chunks", n)
     return planes
 
 
@@ -785,13 +860,15 @@ class PayloadFeed:
       payloads are immutable once parsed, so one verification covers every
       later decode — and the warmup launch that produces the cursors also
       compiles the dispatch);
-    * the packed HUFF words, stacked LUTs and the host-decoded
-      ``ZERO``/``STORE``/``ZLIB`` splice bytes upload **once** and stay
-      resident in device memory;
+    * the packed HUFF words, stacked LUTs, the host-decoded
+      ``ZERO``/``STORE``/``ZLIB`` chunk rows and the assembly's row index
+      upload **once** and stay resident in device memory;
     * :meth:`decode` then re-runs the fused kernel directly from those
-      resident buffers — **zero host→device payload traffic per decode**
-      (asserted via :func:`transfer_stats`), returning device planes
-      byte-identical to ``decode_planes(..., device_resident=True)``.
+      resident buffers and puts the planes together in one compiled
+      assembly (:func:`_assemble`, shared with :func:`decode_planes`) —
+      **zero host→device payload traffic per decode** (asserted via
+      :func:`transfer_stats`), returning device planes byte-identical to
+      ``decode_planes(..., device_resident=True)``.
 
     Residency and caching change wall-clock and memory only, never bytes:
     the kernel consumes the exact words ``_pack_words`` would rebuild, so
@@ -818,12 +895,6 @@ class PayloadFeed:
             )
         self.chunk_bytes = cb
         self._interpret = ops.interpret_mode()
-        # Decode-time assembly needs only (method, raw_len) per chunk; the
-        # payload bytes themselves are not retained host-side.
-        self._meta = [
-            [(int(e.method), int(e.raw_len)) for e in entries]
-            for entries in entries_all
-        ]
 
         flat = [
             (p, c)
@@ -833,9 +904,16 @@ class PayloadFeed:
         with tracing.span("znn.codec.chunk_crc"):
             _verify_payload_crcs(flat, entries_all, payloads_all, pool)
         jobs = _huff_jobs(flat, entries_all, payloads_all, tables_all)
+        others = [
+            (p, c) for (p, c) in flat
+            if entries_all[p][c].method != codec.Method.HUFF
+        ]
+        # Decode-time assembly needs only the row index and the planes'
+        # lengths; the payload bytes themselves are not retained host-side.
+        index, self._lengths = _assembly_plan(entries_all, jobs, others, cb)
 
         self._luts = None
-        self._windows: List[Tuple[Tuple[Tuple[int, int], ...], Any, Any, Any]] = []
+        self._windows: List[Tuple[Any, Any, Any]] = []
         if jobs:
             with tracing.span("znn.codec.luts"):
                 luts, _ = _stacked_luts(tables_all)
@@ -864,60 +942,36 @@ class PayloadFeed:
                 cursors_h = np.asarray(tracing.fetch(cursors), dtype=np.int64)
                 with tracing.span("znn.codec.cursor_check"):
                     _check_cursors(batch, payloads_all, sizes, cursors_h)
-                self._windows.append((tuple(batch), wd, pd, cd))
+                self._windows.append((wd, pd, cd))
 
-        others = [
-            (p, c) for (p, c) in flat
-            if entries_all[p][c].method != codec.Method.HUFF
-        ]
         with tracing.span("znn.codec.host_chunks"):
             other_chunks = _decode_other_chunks(
                 others, entries_all, payloads_all, pool
             )
-        self._splice = None
-        self._splice_off: Dict[Tuple[int, int], Tuple[int, int]] = {}
+        self._rows = None
         if others:
             with tracing.span("znn.codec.splice"):
-                off = 0
-                parts = []
-                for key in others:
-                    piece = other_chunks[key]
-                    self._splice_off[key] = (off, off + piece.size)
-                    parts.append(piece)
-                    off += piece.size
-                cat = np.concatenate(parts) if len(parts) > 1 else parts[0]
-                tracing.count_payload_upload(cat.nbytes)
-                self._splice = jnp.asarray(cat)
+                rows = _other_rows(others, other_chunks, cb)
+                tracing.count_payload_upload(rows.nbytes)
+                self._rows = jnp.asarray(rows)
+        self._index = jnp.asarray(index)
         self.dispatches = self._count_dispatches()
 
     @property
     def n_planes(self) -> int:
-        return len(self._meta)
+        return len(self._lengths)
 
     def _count_dispatches(self) -> int:
-        """Eager device ops one :meth:`decode` issues, from its pieces: a
-        launch per window, a row index (slice + squeeze) per HUFF chunk and
-        a trim where the chunk is short, a slice per spliced chunk unless it
-        is the whole splice buffer, a concatenate per plane of several
-        pieces."""
-        n = len(self._windows)
-        splice_size = 0 if self._splice is None else int(self._splice.size)
-        for p, metas in enumerate(self._meta):
-            for c, (m, raw_len) in enumerate(metas):
-                if m == codec.Method.HUFF:
-                    n += 2 + (raw_len < self.chunk_bytes)
-                else:
-                    lo, hi = self._splice_off[(p, c)]
-                    n += (lo, hi) != (0, splice_size)
-            n += len(metas) > 1
-        return n
+        """Device dispatches one :meth:`decode` issues: a launch per window
+        and the assembly, unless the stream has no chunks."""
+        return len(self._windows) + (int(self._index.shape[0]) > 0)
 
     @property
     def device_bytes(self) -> int:
         """Resident HBM footprint of the feed's payload buffers."""
-        total = sum(int(wd.nbytes) for (_, wd, _, _) in self._windows)
-        if self._splice is not None:
-            total += int(self._splice.nbytes)
+        total = sum(int(wd.nbytes) for (wd, _, _) in self._windows)
+        if self._rows is not None:
+            total += int(self._rows.nbytes)
         return total
 
     def decode(self) -> List[Any]:
@@ -934,12 +988,10 @@ class PayloadFeed:
         return planes
 
     def _decode(self) -> List[Any]:
-        import jax.numpy as jnp
-
         from repro.kernels import huffdecode
 
-        huff_syms: dict = {}
-        for batch, wd, pd, cd in self._windows:
+        sources = []
+        for wd, pd, cd in self._windows:
             # Cursors were integrity-checked at build; the payload words are
             # immutable, so re-checking per decode would re-verify the same
             # bits — drop them without a device→host transfer.
@@ -948,22 +1000,7 @@ class PayloadFeed:
                 chunk_bytes=self.chunk_bytes,
                 interpret=self._interpret,
             )
-            for k, key in enumerate(batch):
-                huff_syms[key] = syms[k]
-
-        planes: List[Any] = []
-        for p, metas in enumerate(self._meta):
-            pieces = []
-            for c, (m, raw_len) in enumerate(metas):
-                if m == codec.Method.HUFF:
-                    pieces.append(huff_syms[(p, c)][:raw_len])
-                else:
-                    lo, hi = self._splice_off[(p, c)]
-                    pieces.append(self._splice[lo:hi])
-            if not pieces:
-                planes.append(np.empty(0, dtype=np.uint8))
-            elif len(pieces) == 1:
-                planes.append(pieces[0])
-            else:
-                planes.append(jnp.concatenate(pieces))
-        return planes
+            sources.append(syms)
+        if self._rows is not None:
+            sources.append(self._rows)
+        return _assemble(sources, self._index, self._lengths)

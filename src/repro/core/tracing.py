@@ -12,8 +12,10 @@ is a view of the two payload counters.
 ``d2h_bytes``          checkpoint paths, and the bytes they bring back
 ``launches.<kernel>``  kernel launches: ``huffdecode``, ``bitpack``,
                        ``plane_producer``, ``plane_consumer``
-``feed_dispatches``    eager device ops issued by payload-feed decodes,
+``feed_dispatches``    device dispatches issued by payload-feed decodes,
                        added once per decode from its piece counts
+``assemblies``         compiled plane assemblies of device-resident
+``assembled_chunks``   decodes, and the chunks they placed
 ``compiles``           XLA compilations, and the seconds spent lowering
 ``compile_s``          and compiling, from JAX's compile-duration events
 =====================  ====================================================
@@ -68,6 +70,8 @@ COUNTERS = (
     "launches.plane_producer",
     "launches.plane_consumer",
     "feed_dispatches",
+    "assemblies",
+    "assembled_chunks",
     "compiles",
     "compile_s",
 )

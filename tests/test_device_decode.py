@@ -18,7 +18,7 @@ import tempfile
 import numpy as np
 import pytest
 
-from repro.core import codec, device_entropy, engine, huffman, zipnn
+from repro.core import codec, container, device_entropy, engine, huffman, zipnn
 from parity import make_array
 
 HUFF_CFG = zipnn.ZipNNConfig(chunk_param_bytes=1 << 15, backend="huffman")
@@ -443,3 +443,212 @@ def test_grad_sync_device_entropy():
     manifest, _ = gs.pack(grads)
     back = gs.unpack(manifest)
     assert np.array_equal(np.asarray(back["g"]), np.asarray(grads["g"]))
+
+
+# ---------------------------------------------------------------------------
+# device plane assembly: parity with the host splice, compiles, counters
+# ---------------------------------------------------------------------------
+
+ASSEMBLY_CASES = (
+    "all_huff", "all_store", "mixed_methods", "short_huff", "short_store",
+    "short_zero", "short_zlib", "two_windows", "empty_plane", "bfloat16",
+    "float32",
+)
+
+
+def _sparse(n: int, seed: int) -> np.ndarray:
+    """Mostly zeros: the delta path's ZLIB chunks."""
+    out = np.zeros(n, dtype=np.uint8)
+    rng = np.random.default_rng(seed)
+    out[rng.integers(0, n, max(1, n // 50))] = 7
+    return out
+
+
+def assembly_stream(name: str):
+    """One named stream for the plane-assembly cases.
+
+    Returns ``(planes, entries, payloads, tables, params, methods,
+    max_batch_bytes)``: the raw planes the stream decodes to (``None`` for
+    the layout cases, whose reference is the host path), the stream, the
+    chunk methods it must hold, and the launch-window cap to decode it at.
+    """
+    cb = 4096
+    huff = codec.CodecParams(chunk_bytes=cb, backend="huffman")
+    delta = dataclasses.replace(huff, delta_mode=True)
+    rng = np.random.default_rng(sum(map(ord, name)))
+
+    def rand(n):
+        return rng.integers(0, 256, n, dtype=np.uint8).astype(np.uint8)
+
+    def zeros(n):
+        return np.zeros(n, dtype=np.uint8)
+
+    H, S, Z, L = (codec.Method.HUFF, codec.Method.STORE, codec.Method.ZERO,
+                  codec.Method.ZLIB)
+    max_batch = device_entropy.MAX_BATCH_BYTES
+    if name in ("bfloat16", "float32"):
+        arr = make_array(name, 3 * 8192 + 1_001, seed=len(name))
+        ct = zipnn.compress_array(arr, HUFF_CFG)
+        meta, mv = container.unpack_stream(ct.blob)
+        payloads = [
+            [container.payload_view(meta, mv, p, c)
+             for c in range(len(meta.entries[p]))]
+            for p in range(meta.n_planes)
+        ]
+        params = codec.CodecParams(chunk_bytes=meta.chunk_bytes, backend="huffman")
+        return (None, meta.entries, payloads, meta.tables, params, {H},
+                max_batch)
+    params = huff
+    if name == "all_huff":
+        planes, methods = [_skewed_plane(3 * cb, seed=41)], {H}
+    elif name == "all_store":
+        planes, methods = [rand(3 * cb)], {S}
+    elif name == "mixed_methods":
+        # incompressible > 1 plans HUFF on random bytes: the guard stores it
+        params = dataclasses.replace(delta, incompressible=1.1)
+        planes = [np.concatenate([
+            zeros(cb), _sparse(cb, 42), _skewed_plane(cb, seed=43), rand(cb),
+            _skewed_plane(cb, seed=44),
+        ])]
+        methods = {H, S, Z, L}
+    elif name == "short_huff":
+        planes, methods = [_skewed_plane(2 * cb + 333, seed=45)], {H}
+    elif name == "short_store":
+        planes, methods = [rand(2 * cb + 333)], {S}
+    elif name == "short_zero":
+        planes = [np.concatenate([_skewed_plane(2 * cb, seed=46), zeros(333)])]
+        methods = {H, Z}
+    elif name == "short_zlib":
+        params = delta
+        planes = [np.concatenate([_skewed_plane(2 * cb, seed=47),
+                                  _sparse(333, 48)])]
+        methods = {H, L}
+    elif name == "two_windows":
+        planes = [
+            _skewed_plane(5 * cb + 100, seed=49),
+            np.concatenate([rand(cb), _skewed_plane(2 * cb, seed=50)]),
+        ]
+        methods, max_batch = {H, S}, 2 * (2 * cb)    # two chunks a launch
+    elif name == "empty_plane":
+        planes = [_skewed_plane(2 * cb + 5, seed=51), zeros(0)]
+        methods = {H}
+    else:
+        raise KeyError(name)
+    entries, payloads, tables = _compress_plane_all(planes, params)
+    short = {"short_huff": H, "short_store": S, "short_zero": Z,
+             "short_zlib": L}.get(name)
+    if short is not None:           # the plane's short last chunk is the method's
+        assert entries[0][-1].method == short and entries[0][-1].raw_len < cb
+    return planes, entries, payloads, tables, params, methods, max_batch
+
+
+@pytest.mark.parametrize("name", ASSEMBLY_CASES)
+def test_assembly_matches_host_path(name, monkeypatch):
+    planes, entries, payloads, tables, params, methods, max_batch = (
+        assembly_stream(name)
+    )
+    assert methods <= {e.method for pe in entries for e in pe}
+    monkeypatch.setattr(device_entropy, "MAX_BATCH_BYTES", max_batch)
+    host = device_entropy.decode_planes(entries, payloads, tables, params)
+    dev = device_entropy.decode_planes(
+        entries, payloads, tables, params, device_resident=True
+    )
+    assert len(dev) == len(host)
+    for d, h in zip(dev, host):
+        assert np.asarray(d).dtype == np.uint8
+        assert np.array_equal(np.asarray(d), h)
+    if planes is not None:
+        for h, p in zip(host, planes):
+            assert np.array_equal(h, p)
+
+
+def test_assembly_refuses_short_chunk_before_plane_end():
+    """A chunk table that cuts a plane short before its last chunk breaks
+    the fixed-stride invariant: the device path refuses it as corrupt."""
+    cb = 4096
+    params = codec.CodecParams(chunk_bytes=cb, backend="huffman")
+    zero_entries, zero_payloads, _ = codec.compress_plane(
+        np.zeros(100, dtype=np.uint8), params
+    )
+    assert [(e.method, e.raw_len) for e in zero_entries] == [
+        (codec.Method.ZERO, 100)
+    ]
+    entries, payloads, table = codec.compress_plane(
+        _skewed_plane(2 * cb, seed=52), params
+    )
+    crafted = [list(zero_entries) + list(entries)]
+    crafted_payloads = [list(zero_payloads) + list(payloads)]
+    with pytest.raises(IOError, match="chunk_bytes strides"):
+        device_entropy.decode_planes(
+            crafted, crafted_payloads, [table], params, device_resident=True
+        )
+    with pytest.raises(IOError, match="chunk_bytes strides"):
+        device_entropy.PayloadFeed(crafted, crafted_payloads, [table], params)
+
+
+def _store_at(k: int, cb: int) -> np.ndarray:
+    """Three chunks, the ``k``-th random (the expansion guard stores it)."""
+    rng = np.random.default_rng(60 + k)
+    parts = [_skewed_plane(cb, seed=61 + k + i) for i in range(3)]
+    parts[k] = rng.integers(0, 256, cb, dtype=np.uint8).astype(np.uint8)
+    return np.concatenate(parts)
+
+
+@pytest.mark.parametrize("via", ["decode_planes", "feed"])
+def test_assembly_compiles_once_per_shape(via):
+    """Streams of one shape whose STORE fallbacks sit at different chunks
+    (same row counts) share one compiled assembly: the row index is an
+    argument, not part of the compile key."""
+    cb = 6144                                   # a shape no other test uses
+    params = codec.CodecParams(
+        chunk_bytes=cb, backend="huffman", incompressible=1.1
+    )
+    assembler = device_entropy._assembler()
+    before = assembler._cache_size()
+    sizes = []
+    for k in (0, 1):
+        plane = _store_at(k, cb)
+        entries, payloads, tables = _compress_plane_all([plane], params)
+        assert [e.method for e in entries[0]].index(codec.Method.STORE) == k
+        if via == "feed":
+            got = device_entropy.PayloadFeed(
+                entries, payloads, tables, params
+            ).decode()
+        else:
+            got = device_entropy.decode_planes(
+                entries, payloads, tables, params, device_resident=True
+            )
+        assert np.array_equal(np.asarray(got[0]), plane)
+        sizes.append(assembler._cache_size())
+    assert sizes == [before + 1, before + 1]
+
+
+@pytest.mark.parametrize("via", ["decode_planes", "feed"])
+def test_assembly_counters(via, monkeypatch):
+    """One assembly per decode call, placing every chunk of the stream."""
+    from repro.core import tracing
+
+    _, entries, payloads, tables, params, _, max_batch = assembly_stream(
+        "two_windows"
+    )
+    monkeypatch.setattr(device_entropy, "MAX_BATCH_BYTES", max_batch)
+    n_chunks = sum(len(pe) for pe in entries)
+    feed = (
+        device_entropy.PayloadFeed(entries, payloads, tables, params)
+        if via == "feed" else None
+    )
+    for _ in range(2):
+        c0 = tracing.counters()
+        if feed is None:
+            device_entropy.decode_planes(
+                entries, payloads, tables, params, device_resident=True
+            )
+        else:
+            feed.decode()
+        c1 = tracing.counters()
+        assert c1["assemblies"] - c0["assemblies"] == 1
+        assert c1["assembled_chunks"] - c0["assembled_chunks"] == n_chunks
+    # the host path assembles nothing on the device
+    c0 = tracing.counters()
+    device_entropy.decode_planes(entries, payloads, tables, params)
+    assert tracing.counters()["assemblies"] == c0["assemblies"]

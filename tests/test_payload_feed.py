@@ -30,6 +30,7 @@ from repro.core import (
     huffman,
     zipnn,
 )
+from test_device_decode import ASSEMBLY_CASES, assembly_stream
 from test_serve_compressed import _lockstep, _tiny
 
 from repro.serve import CompressedParamStore, make_compressed_serve_step
@@ -133,6 +134,42 @@ class TestArrayFeed:
         payloads[victim[0]][victim[1]] = bytes(bad)
         with pytest.raises(IOError, match="CRC mismatch"):
             device_entropy.PayloadFeed(meta.entries, payloads, meta.tables, params)
+
+
+class TestFeedAssembly:
+    """The feed puts its planes together with the same compiled assembly as
+    ``decode_planes(device_resident=True)``: byte-identical to the host
+    path on every stream shape, in a launch per window plus one assembly."""
+
+    @pytest.mark.parametrize("name", ASSEMBLY_CASES)
+    def test_decode_matches_host_path(self, name, monkeypatch):
+        planes, entries, payloads, tables, params, methods, max_batch = (
+            assembly_stream(name)
+        )
+        assert methods <= {e.method for pe in entries for e in pe}
+        monkeypatch.setattr(device_entropy, "MAX_BATCH_BYTES", max_batch)
+        host = device_entropy.decode_planes(entries, payloads, tables, params)
+        feed = device_entropy.PayloadFeed(entries, payloads, tables, params)
+        assert feed.n_planes == len(host)
+        for _ in range(2):                    # every decode, not just the first
+            got = feed.decode()
+            assert len(got) == len(host)
+            for g, h in zip(got, host):
+                assert np.array_equal(np.asarray(g), h)
+        if planes is not None:
+            for h, p in zip(host, planes):
+                assert np.array_equal(h, p)
+
+    @pytest.mark.parametrize("name", ["mixed_methods", "two_windows", "all_store"])
+    def test_decode_issues_counted_dispatches(self, name, monkeypatch):
+        """Every device op :meth:`decode` issues is a top-level equation
+        of its trace: exactly the feed's own count of them."""
+        _, entries, payloads, tables, params, _, max_batch = assembly_stream(name)
+        monkeypatch.setattr(device_entropy, "MAX_BATCH_BYTES", max_batch)
+        feed = device_entropy.PayloadFeed(entries, payloads, tables, params)
+        eqns = jax.make_jaxpr(feed.decode)().eqns
+        assert len(eqns) == feed._count_dispatches() == feed.dispatches
+        assert len(eqns) == len(feed._windows) + 1
 
 
 # ---------------------------------------------------------------------------

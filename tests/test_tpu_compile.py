@@ -131,3 +131,28 @@ def test_huffdecode_compiles(one_chip, itemsize, lut_bits):
         jax.ShapeDtypeStruct((DISPATCH_CHUNKS,), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((itemsize, 1 << lut_bits), jnp.int32, sharding=one_chip),
     )
+
+
+@pytest.mark.parametrize("itemsize", [2, 4], ids=["bf16", "fp32"])
+def test_plane_assembly_compiles(one_chip, itemsize):
+    """The device plane assembly of a whole leaf: half its chunks in a
+    Huffman launch window, half in host-decoded rows, every plane gathered
+    from both and cut to its length."""
+    from repro.core import device_entropy
+
+    cb = CHUNK_BYTES[itemsize]
+    n = LEAF[0] * LEAF[1]                     # bytes in each plane
+    rows = itemsize * -(-n // cb)
+    sources = tuple(
+        jax.ShapeDtypeStruct((half, cb), jnp.uint8, sharding=one_chip)
+        for half in (rows // 2, rows - rows // 2)
+    )
+    index = jax.ShapeDtypeStruct((rows,), jnp.int32, sharding=one_chip)
+    compiled = (
+        device_entropy._assembler()
+        .lower(sources, index, (n,) * itemsize)
+        .compile()
+    )
+    outs = compiled.out_info
+    assert [o.shape for o in outs] == [(n,)] * itemsize
+    assert all(o.dtype == jnp.uint8 for o in outs)
