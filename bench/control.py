@@ -8,10 +8,14 @@ The control is the plain reference put in the program's place, computed
 one precision below the configuration's: fp8 (e4m3) for bf16 state, bf16
 for fp32 state.
 
-* Restore and save cells compare states bit for bit; their number is the
-  count of mismatched elements, limit 0.  The control's reading is the
-  count of elements that the state, rounded to the lower precision and
-  widened back, changes (the state as the cell's set-up makes it).
+* Restore cells compare states bit for bit; their number is the count of
+  mismatched elements, limit 0.  The control's reading is the count of
+  elements that the state, rounded to the lower precision and widened
+  back, changes (the state as the cell's set-up makes it).
+* The save cell runs whole, for ``--seconds``, with each state saved
+  rounded to the lower precision and widened back in place of the state
+  itself; its own check then reads the sampled chunks back against the
+  replayed state, and its numbers are the control's reading.
 * The serve cell compares served tokens with the reference; its number is
   the widest gap by which a served token's logit lies below the
   reference's best.  Each seed runs the cell for ``--seconds``, then reads
@@ -41,10 +45,6 @@ def state_control(parts, seed, jax, overrides=None):
 
     run = harness.Run("control", parts["config"], parts["traffic"], seed, ROOT,
                       overrides or {})
-    traffic = dict(parts["traffic"])
-    if traffic["kind"] == "save":
-        traffic.update(state="train", subtrees=None)
-    run.traffic = traffic
     s, _, _ = restore.make_state(run, jax)
     low = {"bfloat16": jnp.float8_e4m3fn, "float32": jnp.bfloat16}
     changed = 0
@@ -90,11 +90,12 @@ def main(argv=None) -> int:
     parts = harness.load_cell(harness.load_spec(), args.workload)
     harness.device_info(jax, parts["cell"]["chips"])
     for seed in args.seeds:
-        if parts["traffic"]["kind"] == "serve":
+        if parts["traffic"]["kind"] in ("serve", "save"):
             r = harness.run_cell(args.workload, seed, args.seconds, False,
                                  t_start=time.perf_counter(), control=True)
             out = {"seed": seed, "correct": r["correct"], **r["control"],
-                   "tokens": r["attempted"]}
+                   **{k: c["value"] for k, c in r["compared"].items()},
+                   "attempted": r["attempted"]}
         else:
             out = {"seed": seed, **state_control(parts, seed, jax)}
         print(json.dumps(out), flush=True)

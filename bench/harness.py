@@ -9,7 +9,9 @@ added with new files and new entries, never by editing this file.
 Every run: set-up (state from the seed, what the traffic needs, every shape
 of the window warmed), then the window (operations back to back until
 ``seconds`` have passed; the one in flight at the deadline finishes and
-counts), then the check against the plain reference, then one JSON line.
+counts, and a kind with a ``closes(run, ops)`` may ask for more, so that
+the window holds whole units of its traffic), then the check against the
+plain reference, then one JSON line.
 """
 
 from __future__ import annotations
@@ -173,6 +175,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
             opts.host_tracer_level = 1
             jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
         ops = 0
+        closes = getattr(kind, "closes", lambda run, ops: True)
         with span(jax, "window"):
             w0 = time.perf_counter()
             while True:
@@ -180,7 +183,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *, t_start: floa
                 kind.step(run, jax, ops)
                 run.per_op.append(time.perf_counter() - t)
                 ops += 1
-                if time.perf_counter() - w0 >= seconds:
+                if time.perf_counter() - w0 >= seconds and closes(run, ops):
                     break
             window_s = time.perf_counter() - w0
         gc.unfreeze()

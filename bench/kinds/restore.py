@@ -87,11 +87,13 @@ def setup(run, jax):
     ck = znn.Checkpoint(run.workdir / "ckpt")
     newest = ck.steps()[-1]
     steps = decoded_steps(ck, newest)
-    streams = [ck.stream(st, e["key"]) for st in steps for e in ck.manifest(st)["entries"]]
+    pairs = [(ck.stream(st, e["key"]), e["kind"] != "full")     # stream, is an XOR delta
+             for st in steps for e in ck.manifest(st)["entries"]]
     run.extra.update(mgr=mgr, compare=compare, counts=[],
                      raw=state.tree_bytes(s),
                      read_stored=sum(ck.stored_bytes(st) for st in steps),
-                     huffdecode=work.huffdecode_bytes(streams),
+                     huffdecode=work.huffdecode_bytes(st for st, _ in pairs),
+                     plane_consumer=work.plane_bytes(pairs),
                      stored_per_raw=sum(ck.stored_bytes(st) for st in ck.steps())
                      / sum(ck.manifest(st)["raw_bytes"] for st in ck.steps()))
     _, tree = mgr.restore(device_resident=True)             # warm every shape

@@ -1,19 +1,21 @@
-"""Closed-loop training save: one AdamW update of the program's optimizer
-with gradients from the seed, then ``CheckpointManager.save(step, state,
-blocking=True)``; the manager's own base/delta cycle runs on.
+"""Closed-loop training save: ``updates_between_saves`` AdamW updates of the
+program's optimizer with gradients from the seed, then
+``CheckpointManager.save(step, state, blocking=True)``; the manager's own
+base/delta cycle runs on, and a window holds whole cycles of it.
 
 Mix parameters (``traffic/<mix>.json``):
-  ``updates_before_first_save``  AdamW updates made in set-up;
-  ``warm_saves``                 saves made in set-up (a base, then a
-                                 delta), which warm both paths and set
-                                 ``stored_per_raw``;
-  ``sample_chunks``              chunks of each save read back by the
-                                 plain reader, drawn from the seed.
+  ``updates_between_saves``  AdamW updates before each save, set-up's too;
+  ``warm_saves``             saves made in set-up (a base, then a delta),
+                             which warm both paths and set
+                             ``stored_per_raw``;
+  ``sample_chunks``          chunks of each save read back by the plain
+                             reader, drawn from the seed.
 
 After the window the states of all saves are made again by replaying the
 same updates (the compiled update is deterministic), and the sampled chunks
 of every save still on disk are read back by ``reference/znn.py`` and
-compared bit for bit.
+compared bit for bit.  The control saves each state rounded one precision
+down, in its own type, in place of the state itself.
 """
 
 from __future__ import annotations
@@ -38,11 +40,9 @@ def setup(run, jax):
     cfg = state.model_config(run.config, run.overrides.get("model"))
     key = state.seed_key(run.seed)
     init, update = state.make_train_state(cfg)
-    run.extra.update(key=key, update=update, n=0)
+    run.extra.update(key=key, update=update, n=0,
+                     lower=jax.jit(lambda t: jax.tree_util.tree_map(_lower, t)))
     s = init(key)
-    for _ in range(run.traffic["updates_before_first_save"]):
-        s = _upd(run, jax, s, run.extra["n"])
-        run.extra["n"] += 1
     jax.block_until_ready(s)
     t1 = time.perf_counter()
     mgr = program.manager(run.config, run.workdir / "ckpt")
@@ -57,16 +57,31 @@ def setup(run, jax):
                                 "warm_saves_s": f"{time.perf_counter() - t1:.3f}"}
 
 
+# (exponent, mantissa) bits one precision down: bfloat16 for float32, e4m3
+# for bfloat16.  ``reduce_precision`` and not a cast there and back, which
+# XLA may fold away as excess precision.
+LOWER = {"float32": (8, 7), "bfloat16": (4, 3)}
+
+
+def _lower(x):
+    """A leaf rounded one precision down, kept in its own type: the control."""
+    import jax
+
+    return jax.lax.reduce_precision(x, *LOWER[x.dtype.name])
+
+
 def _save_one(run, jax, s):
     from bench.harness import span
 
-    with span(jax, "adamw_update"):
-        s = _upd(run, jax, s, run.extra["n"])
+    with span(jax, "adamw_updates"):
+        for _ in range(run.traffic["updates_between_saves"]):
+            s = _upd(run, jax, s, run.extra["n"])
+            run.extra["n"] += 1
         jax.block_until_ready(s)
-    step = STEP0 + run.extra["n"]
-    run.extra["n"] += 1
+    step = STEP0 + run.extra["n"] - 1
     with span(jax, "save"):
-        run.extra["mgr"].save(step, s, blocking=True)
+        run.extra["mgr"].save(step, run.extra["lower"](s) if run.extra["control"] else s,
+                              blocking=True)
     run.extra["saves"].append((step, run.extra["n"]))
     run.extra["written"] += (run.workdir / "ckpt" / f"step_{step}" / "data.bin").stat().st_size
     return s
@@ -74,6 +89,12 @@ def _save_one(run, jax, s):
 
 def step(run, jax, i):
     run.extra["state"] = _save_one(run, jax, run.extra["state"])
+
+
+def closes(run, ops):
+    """The window closes only on whole base/delta cycles: any ``base_every``
+    saves in a row hold one base, so every window saves the same mix."""
+    return ops % run.config["checkpoint"]["base_every"] == 0
 
 
 def release(run, jax):
@@ -94,7 +115,6 @@ def check(run, jax, ops):
     s, n = init(key), 0
     missing = 0
     latest = run.extra["saves"][-1][0]
-    streams = []
     take = {}
     for step_, n_after in saves:
         while n < n_after:
@@ -105,8 +125,6 @@ def check(run, jax, ops):
             continue
         flat = state.flat_leaves(s)
         keys = sorted(flat)
-        saved = {e["key"] for e in ck.manifest(step_)["entries"]}
-        streams += [ck.stream(step_, k) for k in keys if k in saved]
         sizes = np.array([flat[k].size for k in keys], np.float64)
         for j in rng.choice(len(keys), run.traffic["sample_chunks"], p=sizes / sizes.sum()):
             k = keys[j]
@@ -114,8 +132,8 @@ def check(run, jax, ops):
             c = int(rng.integers(-(-flat[k].size // e)))
             wants.append((step_, k, c))
             expect[(step_, k, c)] = _chunk_words(jax, take, flat[k], c, e)
-    run.extra["bitpack"] = work.bitpack_bytes(streams) if len(streams) and not any(
-        st not in on_disk for st, _ in saves) else None
+    run.extra.update(window_work(ck, saves, run.extra["window_from"],
+                                 run.config["checkpoint"]["base_every"]))
     bad, bad_saves = 0, set()
     try:
         got = ck.read_chunks(wants)
@@ -139,6 +157,35 @@ def check(run, jax, ops):
     return ({"mismatched_elements": {"value": bad, "limit": 0},
              "newest_save_missing": {"value": missing, "limit": 0}},
             len(saves), len(bad_saves) + missing)
+
+
+WORK = ("bitpack", "plane_producer", "chunk_histogram")
+
+
+def window_work(ck, saves, first, base_every):
+    """Bytes each save kernel must move in the window's ``saves`` (the
+    manager's saves from number ``first`` on), by kernel (``WORK``), from
+    their chunk tables.  A save that retention has removed counts as the
+    mean of the saves of its kind still on disk, its kind (base or delta)
+    following from its place in the cycle of ``base_every``; ``{}`` where
+    none of that kind is left."""
+    on_disk = set(ck.steps())
+    got, by_kind = {}, {}
+    for step_, _ in saves:
+        if step_ in on_disk:
+            m = ck.manifest(step_)
+            pairs = [(ck.stream(step_, e["key"]), e["kind"] != "full") for e in m["entries"]]
+            streams = [st for st, _ in pairs]
+            got[step_] = (work.bitpack_bytes(streams), work.plane_bytes(pairs),
+                          work.histogram_bytes(streams))
+            by_kind.setdefault(m["kind"], []).append(got[step_])
+    total = np.zeros(len(WORK))
+    for i, (step_, _) in enumerate(saves, first):
+        kind = "base" if i % base_every == 0 else "delta"
+        if step_ not in got and kind not in by_kind:
+            return {}
+        total += got[step_] if step_ in got else np.mean(by_kind[kind], axis=0)
+    return dict(zip(WORK, map(float, total)))
 
 
 def chunk_elems(config, itemsize):

@@ -1,8 +1,9 @@
 """Share of the traced window the ring's decode worker spent issuing the
-payload feed's device ops: self time of the program's ``znn.feed.decode``
-spans on the worker thread (the Huffman launch, per-chunk slices and
-per-plane concatenates), from ``repro.core.tracing.snapshot()``.  ``None``
-where the program records no spans."""
+payload feed's device work: self time of the program's ``znn.feed.decode``
+spans on the worker thread (the Huffman launch of each window and one
+compiled assembly of the leaf's planes), from
+``repro.core.tracing.snapshot()``.  ``None`` where the program records no
+spans."""
 
 
 def read(m):

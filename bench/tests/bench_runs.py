@@ -19,40 +19,42 @@ HUBERT = {"d_model": 64, "d_ff": 128, "n_heads": 4, "n_kv_heads": 4, "head_dim":
 def overrides(cell):
     return {"model": QWEN if "qwen" in cell else HUBERT,
             "codec": {"chunk_param_bytes": 8192},
-            "traffic": ({"batch": 2, "cache_len": 16, "start_pos": 8, "control_steps": 2}
-                        if cell.startswith("serve") else {})}
+            "traffic": TRAFFIC.get(cell.split(".")[0], {})}
 
 
-# Cells whose code and files are here but which BENCHMARK.json may not list
-# yet; the tests run them all the same.
+TRAFFIC = {"serve": {"batch": 2, "cache_len": 16, "start_pos": 8, "control_steps": 2},
+           "save": {"updates_between_saves": 2}}
+
+
+# A cell whose code and files are here but which BENCHMARK.json does not list
+# (its rate spread too widely on the chip, PERF.md §7); the tests run it all
+# the same, listed for its end-to-end metric and every reader of that metric.
 PENDING = {
-    "config": {"name": "hubert_xlarge-train", "file": "bench/configs/hubert_xlarge-train.json"},
-    "workloads": [("save.hubert_xlarge-train", "save_loop", "save_GBps"),
-                  ("resume.hubert_xlarge-train", "resume_delta", "restore_GBps")],
+    "workload": {"name": "resume.hubert_xlarge-train", "config": "hubert_xlarge-train",
+                 "traffic": "resume_delta", "chips": 1},
+    "moves": "restore_GBps",
+    "per_layer": [{"name": "plane_consumer_roofline.resume", "unit": "%", "better": "higher",
+                   "source": "device_trace", "layer": "kernels", "moves": "restore_GBps"}],
 }
 
 
 def spec():
-    """BENCHMARK.json with the pending cells added where it lacks them."""
+    """BENCHMARK.json with the pending cell added where it lacks it."""
     from bench import harness
 
     s = harness.load_spec()
-    if not any(c["name"] == PENDING["config"]["name"] for c in s["configs"]):
-        s["configs"].append(dict(PENDING["config"]))
-    for name, traffic, metric in PENDING["workloads"]:
-        if any(w["name"] == name for w in s["workloads"]):
-            continue
-        s["workloads"].append({"name": name, "config": PENDING["config"]["name"],
-                               "traffic": traffic, "chips": 1})
-        e2e = next((m for m in s["end_to_end"] if m["name"] == metric), None)
-        if e2e is None:
-            e2e = {"name": metric, "unit": "GB/s", "workloads": []}
-            s["end_to_end"].append(e2e)
-        e2e["workloads"].append(name)
+    cell = PENDING["workload"]["name"]
+    if any(w["name"] == cell for w in s["workloads"]):
+        return s
+    s["workloads"].append(dict(PENDING["workload"]))
+    s["per_layer"] += [dict(m, workloads=[]) for m in PENDING["per_layer"]]
+    for m in s["end_to_end"] + s["per_layer"]:
+        if PENDING["moves"] in (m["name"], m.get("moves")) and "workloads" in m:
+            m["workloads"].append(cell)
     return s
 
 
-def run(cell, seed=2**33 + 5, seconds=0.5, control=False):
+def run(cell, seed=2**33 + 5, seconds=0.5, control=False, trace=False):
     import io
     import tempfile
 
@@ -60,7 +62,7 @@ def run(cell, seed=2**33 + 5, seconds=0.5, control=False):
 
     log = io.StringIO()
     with tempfile.TemporaryDirectory() as work:
-        r = harness.run_cell(cell, seed, seconds, False, t_start=time.perf_counter(),
+        r = harness.run_cell(cell, seed, seconds, trace, t_start=time.perf_counter(),
                              require_tpu=False, overrides=overrides(cell), control=control,
                              workroot=Path(work), spec=spec(), log=log)
     r["log"] = log.getvalue()

@@ -1,5 +1,6 @@
-"""The readers of the program's spans and counters, on a tiny restore and
-ring step of the benchmark's own kinds, traced on the CPU."""
+"""The readers of the program's spans and counters, on a tiny restore,
+resume, save and ring step of the benchmark's own kinds, traced on the
+CPU."""
 
 import math
 import time
@@ -8,14 +9,18 @@ import pytest
 
 from bench_runs import overrides, spec
 
+RESTORE = ["ckpt_host_share.restore", "codec_host_share.restore",
+           "device_wait_share.restore", "host_syncs_per_op.restore"]
 READERS = {
-    "restore.qwen15_4b-L2": ["ckpt_host_share.restore", "codec_host_share.restore",
-                             "device_wait_share.restore", "host_syncs_per_op.restore"],
+    "restore.qwen15_4b-L2": RESTORE,
+    "resume.hubert_xlarge-train": RESTORE,
+    "save.hubert_xlarge-train": ["snapshot_share.save", "write_share.save"],
     "serve.qwen15_4b-L2": ["ring_wait_share.serve", "feed_dispatch_share.serve",
                            "dispatches_per_step.serve"],
 }
 SHARES = {"ckpt_host_share.restore", "codec_host_share.restore", "device_wait_share.restore",
-          "ring_wait_share.serve", "feed_dispatch_share.serve"}
+          "ring_wait_share.serve", "feed_dispatch_share.serve", "snapshot_share.save",
+          "write_share.save"}
 
 
 @pytest.mark.parametrize("cell", sorted(READERS))
@@ -25,8 +30,7 @@ def test_readers_read_the_programs_spans(cell, tmp_path):
     from bench import harness
     from repro.core import tracing
 
-    s = spec()
-    parts = harness.load_cell(s, cell)
+    parts = harness.load_cell(spec(), cell)
     assert set(READERS[cell]) <= {m["name"] for m in parts["per_layer"]}
     ov = overrides(cell)
     config = dict(parts["config"])
@@ -56,9 +60,11 @@ def test_readers_read_the_programs_spans(cell, tmp_path):
         assert v is not None and math.isfinite(v) and v >= 0, (name, v)
         if name in SHARES:
             assert v <= 100.0, (name, v)
-    if cell.startswith("restore"):
+    if cell.startswith(("restore", "resume")):
         assert values["host_syncs_per_op.restore"] >= 1
         assert values["device_wait_share.restore"] > 0
+    elif cell.startswith("save"):
+        assert values["snapshot_share.save"] > 0 and values["write_share.save"] > 0
     else:
         assert values["dispatches_per_step.serve"] > 0
         assert values["ring_wait_share.serve"] > 0

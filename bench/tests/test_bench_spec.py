@@ -29,8 +29,7 @@ def test_cell_resolves_by_name(cell):
         assert callable(getattr(parts["kind"], fn))
     names = {m["name"] for m in parts["end_to_end"]}
     assert "setup_s" in names and len(names) >= 2
-    if cell in {w["name"] for w in SPEC["workloads"]}:
-        assert parts["per_layer"], "every cell reports a per-layer metric"
+    assert parts["per_layer"], "every cell reports a per-layer metric"
 
 
 @pytest.mark.parametrize("entry", WITH_PENDING["configs"], ids=lambda c: c["name"])
@@ -43,12 +42,20 @@ def test_config_file(entry):
     assert config["codec"]["coder"] == "huffman"
 
 
-@pytest.mark.parametrize("metric", SPEC["per_layer"], ids=lambda m: m["name"])
+@pytest.mark.parametrize("metric", WITH_PENDING["per_layer"], ids=lambda m: m["name"])
 def test_per_layer_metric_has_reader_and_moves(metric):
     assert callable(harness.load_metric(metric["name"]))
     for cell in metric["workloads"]:
-        reported = {m["name"] for m in harness.load_cell(SPEC, cell)["end_to_end"]}
+        reported = {m["name"] for m in harness.load_cell(WITH_PENDING, cell)["end_to_end"]}
         assert metric["moves"] in reported
+
+
+def test_save_rate_is_the_save_cells_alone():
+    """``save_GBps`` lists exactly the cells whose traffic saves."""
+    saving = [w["name"] for w in SPEC["workloads"]
+              if harness.load_cell(SPEC, w["name"])["traffic"]["kind"] == "save"]
+    listed = next(m for m in SPEC["end_to_end"] if m["name"] == "save_GBps")["workloads"]
+    assert listed == saving and "save.hubert_xlarge-train" in saving
 
 
 def test_contract_shape():

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 import json
 from pathlib import Path
-from typing import Dict, Iterable
+from typing import Dict, Iterable, Tuple
 
 from bench.reference.znn import HUFF, Stream
 
@@ -42,3 +42,25 @@ def huffdecode_bytes(streams: Iterable[Stream]) -> int:
 def bitpack_bytes(streams: Iterable[Stream]) -> int:
     """Huffman bit-pack: read each HUFF chunk's symbols, write its payload."""
     return sum(ch.raw_len + ch.comp_len for ch in huff_chunks(streams))
+
+
+def raw_bytes(st: Stream) -> int:
+    """A stream's raw bytes: its chunks' lengths over every plane."""
+    return sum(ch.raw_len for row in st.chunks for ch in row)
+
+
+def plane_bytes(streams: Iterable[Tuple[Stream, bool]]) -> int:
+    """Plane producer or consumer: each leaf's raw words on one side and its
+    byte planes on the other, and its base's words where it is an XOR delta
+    (``True`` beside its stream)."""
+    return sum(raw_bytes(st) * (3 if xor else 2) for st, xor in streams)
+
+
+HIST_BINS_BYTES = 256 * 4        # one chunk of one plane: 256 int32 bins
+
+
+def histogram_bytes(streams: Iterable[Stream]) -> int:
+    """Probe histograms: read every byte of every plane, write 256 bins for
+    each chunk of each plane."""
+    return sum(raw_bytes(st) + HIST_BINS_BYTES * sum(len(row) for row in st.chunks)
+               for st in streams)
