@@ -31,6 +31,12 @@ class ModelConfig:
     window: int = 0                # sliding-window size (0 = full attention)
     qkv_bias: bool = False
     rope_theta: float = 1e4
+    # YaRN RoPE scaling (DeepSeek-V2): 0 = plain RoPE.  ``yarn_factor`` is
+    # the context extension (the correction range is DeepSeek-V2's, in
+    # ``models/layers.py``); ``yarn_mscale_all_dim`` (0 = none) scales
+    # MLA's softmax.
+    yarn_factor: float = 0.0
+    yarn_mscale_all_dim: float = 0.0
     use_rope: bool = True
     pos_embedding: str = "rope"    # rope | learned | none
     max_position: int = 32768      # learned-pos table length
@@ -42,7 +48,7 @@ class ModelConfig:
 
     # MLA (DeepSeek-V2)
     mla: bool = False
-    q_lora_rank: int = 0
+    q_lora_rank: int = 0           # 0 = direct query projection (no q-LoRA)
     kv_lora_rank: int = 0
     qk_nope_dim: int = 128
     qk_rope_dim: int = 64
@@ -50,9 +56,12 @@ class ModelConfig:
 
     # MoE
     moe: bool = False
-    n_experts: int = 0
+    n_experts: int = 0             # the router's width
+    experts_held: int = 0          # experts this layer holds, the router's
+                                   # first ones (0 = all n_experts)
     n_shared_experts: int = 0
     experts_per_token: int = 0
+    norm_topk_prob: bool = True    # renormalise the top-k gates to sum 1
     moe_d_ff: int = 0
     first_k_dense: int = 0
     dense_d_ff: int = 0            # width of the leading dense layers
@@ -102,6 +111,12 @@ class ModelConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
 
     @property
+    def n_experts_held(self) -> int:
+        """Experts whose weights this layer holds: the first of the router's
+        ``n_experts`` outputs (a chip's block under expert parallelism)."""
+        return self.experts_held or self.n_experts
+
+    @property
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
@@ -142,9 +157,10 @@ class ModelConfig:
             q_block=64,
             kv_block=64,
             n_experts=8 if self.moe else 0,
+            experts_held=min(self.experts_held, 8) if self.moe else 0,
             experts_per_token=min(self.experts_per_token, 2),
             moe_d_ff=64 if self.moe else 0,
-            q_lora_rank=32 if self.mla else 0,
+            q_lora_rank=32 if self.mla and self.q_lora_rank else 0,
             kv_lora_rank=16 if self.mla else 0,
             qk_nope_dim=32 if self.mla else 128,
             qk_rope_dim=16 if self.mla else 64,

@@ -18,7 +18,15 @@ is a view of the two payload counters.
 ``assembled_chunks``   decodes, and the chunks they placed
 ``compiles``           XLA compilations, and the seconds spent lowering
 ``compile_s``          and compiling, from JAX's compile-duration events
+``ring_raw_bytes.*``   raw bytes the serving ring decoded, by block kind
+                       (``dense``, ``moe``, ``ssm``), added once per job
 =====================  ====================================================
+
+The serving ring's spans: ``znn.ring.step`` (root of one decode step),
+``znn.ring.wait`` (the caller blocked on a layer's weights),
+``znn.ring.layer`` (one layer's compute dispatched), ``znn.ring.tail``
+(cache write, final norm, head), ``znn.ring.decode`` (one decode job) and,
+nested in it, ``znn.ring.experts`` (the job of a ``moe_layers`` layer).
 
 Spans are on exactly while a profiler session is active
 (``jax.profiler.trace`` / ``start_trace``, or a profiler server capturing).
@@ -74,6 +82,9 @@ COUNTERS = (
     "assembled_chunks",
     "compiles",
     "compile_s",
+    "ring_raw_bytes.dense",
+    "ring_raw_bytes.moe",
+    "ring_raw_bytes.ssm",
 )
 
 # Span records kept per profiler session; past the bound the oldest

@@ -115,7 +115,7 @@ _PARAM_RULES = [
     # d_model dim over 'data' on top of expert parallelism over 'model'
     (r"experts/(w_gate|w_up)$", (("experts",), ("zero3",), None)),
     (r"experts/w_down$", (("experts",), None, ("zero3",))),
-    (r"(wq|wk|wv|w_uq|w_uk|w_uv)/w$", (("zero3",), ("heads",))),
+    (r"(wq|wk|wv|w_q|w_uq|w_uk|w_uv)/w$", (("zero3",), ("heads",))),
     (r"(wq|wk|wv)/b$", (("heads",),)),
     (r"wo/w$", (("heads",), ("zero3",))),
     # SwiGLU/GELU MLP leaves are raw arrays (no trailing '/w')

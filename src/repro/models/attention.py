@@ -43,8 +43,8 @@ def _qkv(p, x, cfg, positions, pos_thw=None):
         q = layers.apply_mrope(q, pos_thw, cfg.rope_theta, cfg.mrope_sections)
         k = layers.apply_mrope(k, pos_thw, cfg.rope_theta, cfg.mrope_sections)
     elif cfg.use_rope:
-        q = layers.apply_rope(q, positions, cfg.rope_theta)
-        k = layers.apply_rope(k, positions, cfg.rope_theta)
+        q = layers.apply_rope(q, positions, cfg.rope_theta, cfg.yarn_factor)
+        k = layers.apply_rope(k, positions, cfg.rope_theta, cfg.yarn_factor)
     return q, k, v
 
 
@@ -283,8 +283,8 @@ def gqa_decode(p, x, cache_k, cache_v, pos, cfg) -> Tuple[Array, Array, Array]:
     v = layers.dense(p["wv"], x).reshape(B, 1, cfg.n_kv_heads, hd)
     if cfg.use_rope:
         posb = jnp.full((B, 1), pos, jnp.int32)
-        q = layers.apply_rope(q, posb, cfg.rope_theta)
-        k = layers.apply_rope(k, posb, cfg.rope_theta)
+        q = layers.apply_rope(q, posb, cfg.rope_theta, cfg.yarn_factor)
+        k = layers.apply_rope(k, posb, cfg.rope_theta, cfg.yarn_factor)
 
     from repro.distributed.sharding import axis_size, lshard
 
@@ -329,39 +329,63 @@ def gqa_decode(p, x, cache_k, cache_v, pos, cfg) -> Tuple[Array, Array, Array]:
 # ---------------------------------------------------------------------------
 
 def init_mla(key, cfg):
+    """MLA weights.  Queries take the low-rank path (``w_dq`` →
+    ``q_norm`` → ``w_uq``) when ``cfg.q_lora_rank`` is set, else one
+    direct projection ``w_q`` (d_model → H·(dn+dr); DeepSeek-V2-Lite's
+    ``q_lora_rank: null``).  Keys and values always go through the
+    ``kv_lora_rank`` latent, which is what the decode cache holds."""
     d = cfg.d_model
     H = cfg.n_heads
+    dq = H * (cfg.qk_nope_dim + cfg.qk_rope_dim)
     ks = jax.random.split(key, 8)
-    p = {
-        "w_dq": layers.init_dense(ks[0], d, cfg.q_lora_rank, cfg.dtype),
-        "q_norm": layers.init_rmsnorm(cfg.q_lora_rank, cfg.dtype),
-        "w_uq": layers.init_dense(
-            ks[1], cfg.q_lora_rank, H * (cfg.qk_nope_dim + cfg.qk_rope_dim), cfg.dtype
-        ),
+    if cfg.q_lora_rank:
+        p = {
+            "w_dq": layers.init_dense(ks[0], d, cfg.q_lora_rank, cfg.dtype),
+            "q_norm": layers.init_rmsnorm(cfg.q_lora_rank, cfg.dtype),
+            "w_uq": layers.init_dense(ks[1], cfg.q_lora_rank, dq, cfg.dtype),
+        }
+    else:
+        p = {"w_q": layers.init_dense(ks[1], d, dq, cfg.dtype)}
+    p.update({
         "w_dkv": layers.init_dense(ks[2], d, cfg.kv_lora_rank, cfg.dtype),
         "kv_norm": layers.init_rmsnorm(cfg.kv_lora_rank, cfg.dtype),
         "w_uk": layers.init_dense(ks[3], cfg.kv_lora_rank, H * cfg.qk_nope_dim, cfg.dtype),
         "w_uv": layers.init_dense(ks[4], cfg.kv_lora_rank, H * cfg.v_head_dim, cfg.dtype),
         "w_kr": layers.init_dense(ks[5], d, cfg.qk_rope_dim, cfg.dtype),
         "wo": layers.init_dense(ks[6], H * cfg.v_head_dim, d, cfg.dtype),
-    }
+    })
     return p
+
+
+def _mla_query(p, x, cfg):
+    """Queries (..., H·(dn+dr)) through q-LoRA or the direct ``w_q``."""
+    if "w_q" in p:
+        return layers.dense(p["w_q"], x)
+    cq = layers.rmsnorm(p["q_norm"], layers.dense(p["w_dq"], x), cfg.norm_eps)
+    return layers.dense(p["w_uq"], cq)
+
+
+def mla_softmax_mscale(cfg) -> float:
+    """Factor on MLA's softmax scale ``(dn+dr)^-0.5``: YaRN's
+    ``mscale(factor, mscale_all_dim)²`` where the config sets both, else 1."""
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        return layers.yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return 1.0
 
 
 def _mla_qkv(p, x, cfg, positions):
     B, S, _ = x.shape
     H, dn, dr, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
-    cq = layers.rmsnorm(p["q_norm"], layers.dense(p["w_dq"], x))
-    q = layers.dense(p["w_uq"], cq).reshape(B, S, H, dn + dr)
+    q = _mla_query(p, x, cfg).reshape(B, S, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = layers.apply_rope(q_rope, positions, cfg.rope_theta, cfg.yarn_factor)
 
     from repro.distributed.sharding import lshard
 
-    c_kv = layers.rmsnorm(p["kv_norm"], layers.dense(p["w_dkv"], x))   # (B,S,r)
+    c_kv = layers.rmsnorm(p["kv_norm"], layers.dense(p["w_dkv"], x), cfg.norm_eps)   # (B,S,r)
     c_kv = lshard(c_kv, "batch", None, None)       # latent replicated over TP
     k_rope = layers.dense(p["w_kr"], x).reshape(B, S, 1, dr)
-    k_rope = layers.apply_rope(k_rope, positions, cfg.rope_theta)      # shared head
+    k_rope = layers.apply_rope(k_rope, positions, cfg.rope_theta, cfg.yarn_factor)  # shared head
     k_nope = layers.dense(p["w_uk"], c_kv).reshape(B, S, H, dn)
     val = layers.dense(p["w_uv"], c_kv).reshape(B, S, H, dv)
     # pin head sharding through attention: the up-projections' outputs are
@@ -380,6 +404,9 @@ def _mla_qkv(p, x, cfg, positions):
 
 def mla_train(p, x, cfg, positions) -> Array:
     q, k, v, _, _ = _mla_qkv(p, x, cfg, positions)
+    m = mla_softmax_mscale(cfg)
+    if m != 1.0:                # the attention kernels scale by (dn+dr)^-0.5
+        q = (q.astype(jnp.float32) * m).astype(q.dtype)
     out = _attend(q, k, v, cfg, causal=True)
     B, S = x.shape[:2]
     return layers.dense(p["wo"], out.reshape(B, S, -1))
@@ -406,15 +433,15 @@ def mla_decode(p, x, c_kv_cache, k_rope_cache, pos, cfg):
         cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim, cfg.kv_lora_rank
     )
     L = c_kv_cache.shape[1]
-    cq = layers.rmsnorm(p["q_norm"], layers.dense(p["w_dq"], x))
-    q = layers.dense(p["w_uq"], cq).reshape(B, H, dn + dr)
+    q = _mla_query(p, x, cfg).reshape(B, H, dn + dr)
     q_nope, q_rope = q[..., :dn], q[..., dn:]
     posb = jnp.full((B, 1), pos, jnp.int32)
-    q_rope = layers.apply_rope(q_rope.reshape(B, 1, H, dr), posb, cfg.rope_theta).reshape(B, H, dr)
+    q_rope = layers.apply_rope(q_rope.reshape(B, 1, H, dr), posb, cfg.rope_theta,
+                              cfg.yarn_factor).reshape(B, H, dr)
 
-    c_kv_new = layers.rmsnorm(p["kv_norm"], layers.dense(p["w_dkv"], x))  # (B,1,r)
+    c_kv_new = layers.rmsnorm(p["kv_norm"], layers.dense(p["w_dkv"], x), cfg.norm_eps)  # (B,1,r)
     k_rope_new = layers.apply_rope(
-        layers.dense(p["w_kr"], x).reshape(B, 1, 1, dr), posb, cfg.rope_theta
+        layers.dense(p["w_kr"], x).reshape(B, 1, 1, dr), posb, cfg.rope_theta, cfg.yarn_factor
     ).reshape(B, 1, dr)
     slot = (pos % L).astype(jnp.int32)
 
@@ -434,7 +461,7 @@ def mla_decode(p, x, c_kv_cache, k_rope_cache, pos, cfg):
     )
     from repro.distributed.sharding import lshard
 
-    scale = (dn + dr) ** -0.5
+    scale = (dn + dr) ** -0.5 * mla_softmax_mscale(cfg)
     idx = jnp.arange(L)
     written = jnp.where(pos >= L, idx != slot, idx < pos)
     s = jnp.where(written[None, None, :], s * scale, NEG_INF)

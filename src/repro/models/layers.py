@@ -10,6 +10,7 @@ Conventions:
 
 from __future__ import annotations
 
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -64,14 +65,49 @@ def layernorm(p, x: Array, eps: float = 1e-5) -> Array:
 # RoPE — standard and multimodal (M-RoPE, Qwen2-VL §2.1)
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+# YaRN's correction range as DeepSeek-V2 publishes it: the blend of
+# frequencies ramps from the pair that turns YARN_BETA_FAST times within
+# YARN_ORIGINAL_MAX positions to the pair that turns YARN_BETA_SLOW times.
+YARN_ORIGINAL_MAX = 4096
+YARN_BETA_FAST = 32
+YARN_BETA_SLOW = 1
 
 
-def apply_rope(x: Array, positions: Array, theta: float) -> Array:
-    """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
+def rope_freqs(head_dim: int, theta: float, yarn_factor: float = 0.0) -> Array:
+    """Inverse frequencies of the ``head_dim / 2`` rotation pairs.
+
+    A ``yarn_factor`` applies YaRN (arXiv:2309.00071, as DeepSeek-V2
+    configures it): each pair's frequency blends the interpolated
+    ``1 / (factor · theta^(2i/d))`` and the extrapolated ``1 / theta^(2i/d)``
+    by a linear ramp over the pairs, extrapolated below the correction
+    range and interpolated above it.
+    """
+    pos_freqs = theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim)
+    if not yarn_factor:
+        return 1.0 / pos_freqs
+
+    def pair(rotations):
+        return (head_dim * math.log(YARN_ORIGINAL_MAX / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair(YARN_BETA_FAST)), 0)
+    high = min(math.ceil(pair(YARN_BETA_SLOW)), head_dim - 1)
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    return (1.0 / (yarn_factor * pos_freqs)) * ramp + (1.0 / pos_freqs) * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 · mscale · ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def apply_rope(x: Array, positions: Array, theta: float, yarn_factor: float = 0.0) -> Array:
+    """x: (..., S, H, hd); positions: broadcastable to (..., S).  ``yarn_factor``:
+    see :func:`rope_freqs`.  YaRN's cos and sin are not rescaled: DeepSeek-V2
+    sets ``mscale`` equal to ``mscale_all_dim``, whose ratio is the factor."""
     hd = x.shape[-1]
-    freqs = rope_freqs(hd, theta)                     # (hd/2,)
+    freqs = rope_freqs(hd, theta, yarn_factor)        # (hd/2,)
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     cos = jnp.cos(angles)[..., None, :]               # (..., S, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]

@@ -1,5 +1,16 @@
 """Mixture-of-Experts: top-k router + shard-local sorted capacity dispatch.
 
+What a layer computes: a softmax router over all ``n_experts`` picks the
+top ``experts_per_token`` per token (gates renormalised to sum 1 unless
+``norm_topk_prob`` is off); the layer holds the weights of the first
+``n_experts_held`` of them (one chip's share under expert parallelism,
+its experts ordered first in its router) and adds the gated outputs of
+its own experts only, plus the shared experts, which every holder
+computes.
+Where the held block is all of the router's experts this is the whole
+layer.  A forward drops tokens past each expert's capacity; decode drops
+none.
+
 Design notes (the two decisions that dominate MoE roofline behaviour):
 
 * **Shard-local dispatch.** Token sorting/dispatch happens independently
@@ -32,10 +43,12 @@ Array = jax.Array
 
 
 def init_moe(key, cfg):
-    """Experts as stacked SwiGLU: (E, d_model, moe_d_ff) / (E, moe_d_ff, d_model)."""
+    """Experts as stacked SwiGLU: (E_held, d_model, moe_d_ff) /
+    (E_held, moe_d_ff, d_model) for the held block; the router is
+    (d_model, n_experts) fp32, the router's full width."""
     kr, ke, ks = jax.random.split(key, 3)
     E, d, f = cfg.n_experts, cfg.d_model, cfg.moe_d_ff
-    expert_keys = jax.random.split(ke, E)
+    expert_keys = jax.random.split(ke, cfg.n_experts_held)
     experts = jax.vmap(
         lambda k: layers.init_swiglu(k, d, f, cfg.dtype)
     )(expert_keys)
@@ -69,7 +82,7 @@ def _dispatch_one(xt: Array, idx: Array, C: int, E: int):
     bounds = jnp.searchsorted(sorted_e, jnp.arange(E + 1))
     group_start, group_end = bounds[:-1], bounds[1:]
     pos_in_group = jnp.arange(T * K) - group_start[sorted_e]
-    keep = pos_in_group < C
+    keep = (pos_in_group < C) & (sorted_e < E)  # idx == E: an expert not held
 
     # slot (e, c) ← token sort[group_start[e] + c] when c < group size
     slot_src = group_start[:, None] + jnp.arange(C)[None, :]      # (E, C)
@@ -134,11 +147,22 @@ _combine_one.defvjp(_combine_one_fwd, _combine_one_bwd)
 
 
 def moe_apply(p, x: Array, cfg) -> Tuple[Array, Array]:
-    """x: (B, S, D) → (y (B, S, D), aux_loss scalar)."""
+    """x: (B, S, D) → (y (B, S, D), aux_loss scalar).
+
+    Routes over all ``cfg.n_experts`` and adds the gated outputs of the
+    held experts only (the router's first ``n_experts_held``; a token's
+    other picks belong to other holders and contribute nothing here), then
+    the shared experts.  Gates are the top-k softmax probabilities,
+    renormalised over the k when ``cfg.norm_topk_prob``.  In decode (``S``
+    of 1) each held expert's capacity is the token count, so no token is
+    dropped; in every other forward it is ``int(T·K/E·capacity_factor) + 1``
+    and overflow is dropped.
+    """
     from repro.distributed.sharding import lshard
 
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.experts_per_token
+    E_held = cfg.n_experts_held
     DS = max(1, cfg.dispatch_shards)
     T = B * S
     assert T % DS == 0, (T, DS)
@@ -148,16 +172,24 @@ def moe_apply(p, x: Array, cfg) -> Tuple[Array, Array]:
     logits = layers.dense(p["router"], xt, compute_dtype=jnp.float32)  # (DS,T,E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate, idx = jax.lax.top_k(probs, K)                                # (DS,T,K)
-    gate = gate / jnp.maximum(jnp.sum(gate, axis=-1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gate = gate / jnp.maximum(jnp.sum(gate, axis=-1, keepdims=True), 1e-9)
 
     # Switch-style load-balance aux loss (global means — cheap scalars)
     me = jnp.mean(probs, axis=(0, 1))
     ce = jnp.mean(jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32), axis=(0, 1))
     aux = E * jnp.sum(me * ce)
 
-    C = int(T_loc * K / E * cfg.capacity_factor) + 1
+    if E_held != E:
+        # picks of experts not held go to slot E_held, which the dispatch
+        # never keeps
+        idx = jnp.minimum(idx, E_held)
 
-    buf, sort, pos = jax.vmap(lambda xx, ii: _dispatch_one(xx, ii, C, E))(xt, idx)
+    # decode (one position a sequence) drops no token: a token picks K
+    # distinct experts, so T_loc slots hold all of an expert's tokens
+    C = T_loc if S == 1 else int(T_loc * K / E * cfg.capacity_factor) + 1
+
+    buf, sort, pos = jax.vmap(lambda xx, ii: _dispatch_one(xx, ii, C, E_held))(xt, idx)
     # Scatter stays token-sharded (local; replicated within the model group).
     buf = lshard(buf, "batch", None, None, None)
     # EP layout is token-count-adaptive (§Perf):

@@ -12,15 +12,18 @@ Cache sharding policy (decode cells):
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Callable, Dict, Tuple
 
 import jax
 from jax.sharding import PartitionSpec as P
 
 from repro.core import tracing
-from repro.models.model import Model
+from repro.models.model import Model, _slot_write
 
 PyTree = Any
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 def _div(n: int, mesh, axis: str) -> bool:
@@ -44,7 +47,7 @@ def inference_param_specs(model: Model, mesh) -> PyTree:
         path = "/".join(str(getattr(k, "key", k)) for k in path_tuple)
         nd = leaf.ndim
         if "experts/" in path and cfg.n_experts:
-            e_ax = "data" if _div(cfg.n_experts, mesh, "data") else None
+            e_ax = "data" if _div(cfg.n_experts_held, mesh, "data") else None
             f_ax = "model" if _div(cfg.moe_d_ff, mesh, "model") else None
             pad = [None] * (nd - 3)
             if path.endswith("w_down"):
@@ -185,6 +188,17 @@ def _decode_front(cfg, sp, tokens, pos):
     return lshard(x, "batch", None, None)
 
 
+@jax.jit
+def _write_slots(c0, c1, outs0, outs1, slot):
+    """The post-loop slot write of both caches, as decode_step does it, in
+    one compiled call: run eagerly, the one-hot select would materialise a
+    cache-sized position iota and mask (6.7 GB at a 3 GB latent cache)."""
+    import jax.numpy as jnp
+
+    return (_slot_write(c0, jnp.stack(outs0), slot),
+            _slot_write(c1, jnp.stack(outs1), slot))
+
+
 def _decode_tail(cfg, sp, x):
     from repro.models import blocks, layers
 
@@ -301,8 +315,6 @@ def make_compressed_serve_step(
     import jax.numpy as jnp
     from concurrent.futures import ThreadPoolExecutor
 
-    from repro.models.model import _slot_write
-
     cfg = model.cfg
     if cfg.family == "hybrid":
         raise NotImplementedError(
@@ -344,11 +356,16 @@ def make_compressed_serve_step(
 
     def _decode(n: int):
         j, t = divmod(n, tiles)
-        key, i, _ = plan[j]
+        key, i, kind = plan[j]
         with tracing.span("znn.ring.decode"):
-            if tiles == 1:
-                return store.decode_layer(key, i)
-            return store.decode_layer_tile(key, i, t, tiles)
+            with tracing.span("znn.ring.experts") if kind == "moe" else _NO_SPAN:
+                if tiles == 1:
+                    out = store.decode_layer(key, i)
+                else:
+                    out = store.decode_layer_tile(key, i, t, tiles)
+        tracing.count(f"ring_raw_bytes.{kind}",
+                      sum(a.nbytes for a in jax.tree_util.tree_leaves(out)))
+        return out
 
     def _job(n: int, op):
         with tracing.joined(op):
@@ -459,13 +476,8 @@ def make_compressed_serve_step(
                 outs1.append(u1)
             # single slot write for all layers, exactly as decode_step
             with tracing.span("znn.ring.tail"):
-                n0, n1 = jnp.stack(outs0), jnp.stack(outs1)
-                if cfg.mla:
-                    new_state["mla_ckv"] = _slot_write(c0, n0, slot)
-                    new_state["mla_kr"] = _slot_write(c1, n1, slot)
-                else:
-                    new_state["kv_k"] = _slot_write(c0, n0, slot)
-                    new_state["kv_v"] = _slot_write(c1, n1, slot)
+                keys = ("mla_ckv", "mla_kr") if cfg.mla else ("kv_k", "kv_v")
+                new_state.update(zip(keys, _write_slots(c0, c1, outs0, outs1, slot)))
 
         with tracing.span("znn.ring.tail"):
             logits = _decode_tail(cfg, store.static, x)
