@@ -476,6 +476,10 @@ def _pack_words(
             raise ValueError(
                 "corrupt Huffman payload: payload larger than its chunk"
             )
+        if entries_all[p][ch].raw_len > chunk_bytes:
+            raise ValueError(
+                "corrupt chunk entry: HUFF chunk longer than chunk_bytes"
+            )
         pad = -len(payload) % 4
         w = np.frombuffer(bytes(payload) + b"\x00" * pad, dtype=">u4")
         words[k * cw : k * cw + w.size] = w
@@ -546,11 +550,24 @@ def _unpack_jobs(
             chunk_bytes=chunk_bytes,
             interpret=ops.interpret_mode(),
         )
-    tracing.count("launches.huffdecode")
+    _count_huffdecode(len(jobs), chunk_bytes, luts.size)
     cursors_h = np.asarray(tracing.fetch(cursors), dtype=np.int64)
     with tracing.span("znn.codec.cursor_check"):
         _check_cursors(jobs, payloads_all, sizes, cursors_h)
     return syms
+
+
+def _count_huffdecode(c: int, chunk_bytes: int, lut_words: int) -> Tuple[int, int]:
+    """Count one decode launch of ``c`` chunks: the launch, its chunks and
+    its lane slots (from the launch's static shapes).  Returns the chunk
+    and lane-slot counts."""
+    from repro.kernels import huffdecode
+
+    slots = huffdecode.lane_slots(c, chunk_bytes, lut_words)
+    tracing.count("launches.huffdecode")
+    tracing.count("huffdecode_chunks", c)
+    tracing.count("huffdecode_lane_slots", slots)
+    return c, slots
 
 
 def _verify_payload_crcs(
@@ -914,6 +931,7 @@ class PayloadFeed:
 
         self._luts = None
         self._windows: List[Tuple[Any, Any, Any]] = []
+        self._huff_chunks = self._lane_slots = 0     # per decode, all windows
         if jobs:
             with tracing.span("znn.codec.luts"):
                 luts, _ = _stacked_luts(tables_all)
@@ -938,7 +956,9 @@ class PayloadFeed:
                         chunk_bytes=cb,
                         interpret=self._interpret,
                     )
-                tracing.count("launches.huffdecode")
+                chunks, slots = _count_huffdecode(len(batch), cb, luts.size)
+                self._huff_chunks += chunks
+                self._lane_slots += slots
                 cursors_h = np.asarray(tracing.fetch(cursors), dtype=np.int64)
                 with tracing.span("znn.codec.cursor_check"):
                     _check_cursors(batch, payloads_all, sizes, cursors_h)
@@ -984,6 +1004,8 @@ class PayloadFeed:
         with tracing.span("znn.feed.decode"):
             planes = self._decode()
         tracing.count("launches.huffdecode", len(self._windows))
+        tracing.count("huffdecode_chunks", self._huff_chunks)
+        tracing.count("huffdecode_lane_slots", self._lane_slots)
         tracing.count("feed_dispatches", self.dispatches)
         return planes
 

@@ -5,22 +5,27 @@ program moves payload bytes, fetches from the device, launches a kernel or
 compiles.  :func:`counters` reads them; ``device_entropy.transfer_stats()``
 is a view of the two payload counters.
 
-=====================  ====================================================
-``payload_uploads``    payload-sized host→device uploads on the entropy
-``payload_bytes``      paths (HUFF symbols, packed words, non-HUFF splice)
-``d2h_fetches``        blocking device→host fetches on the codec and
-``d2h_bytes``          checkpoint paths, and the bytes they bring back
-``launches.<kernel>``  kernel launches: ``huffdecode``, ``bitpack``,
-                       ``plane_producer``, ``plane_consumer``
-``feed_dispatches``    device dispatches issued by payload-feed decodes,
-                       added once per decode from its piece counts
-``assemblies``         compiled plane assemblies of device-resident
-``assembled_chunks``   decodes, and the chunks they placed
-``compiles``           XLA compilations, and the seconds spent lowering
-``compile_s``          and compiling, from JAX's compile-duration events
-``ring_raw_bytes.*``   raw bytes the serving ring decoded, by block kind
-                       (``dense``, ``moe``, ``ssm``), added once per job
-=====================  ====================================================
+=========================  ====================================================
+``payload_uploads``        payload-sized host→device uploads on the entropy
+``payload_bytes``          paths (HUFF symbols, packed words, non-HUFF splice)
+``d2h_fetches``            blocking device→host fetches on the codec and
+``d2h_bytes``              checkpoint paths, and the bytes they bring back
+``launches.<kernel>``      kernel launches: ``huffdecode``, ``bitpack``,
+                           ``plane_producer``, ``plane_consumer``
+``huffdecode_chunks``      HUFF chunks the decode launches decoded, and the
+``huffdecode_lane_slots``  lanes they ran (interleaved chunks a grid step
+                           times grid steps, idle lanes included), both from
+                           the launches' static shapes; chunks over slots is
+                           the lane fill
+``feed_dispatches``        device dispatches issued by payload-feed decodes,
+                           added once per decode from its piece counts
+``assemblies``             compiled plane assemblies of device-resident
+``assembled_chunks``       decodes, and the chunks they placed
+``compiles``               XLA compilations, and the seconds spent lowering
+``compile_s``              and compiling, from JAX's compile-duration events
+``ring_raw_bytes.*``       raw bytes the serving ring decoded, by block kind
+                           (``dense``, ``moe``, ``ssm``), added once per job
+=========================  ====================================================
 
 The serving ring's spans: ``znn.ring.step`` (root of one decode step),
 ``znn.ring.wait`` (the caller blocked on a layer's weights),
@@ -74,6 +79,8 @@ COUNTERS = (
     "d2h_fetches",
     "d2h_bytes",
     "launches.huffdecode",
+    "huffdecode_chunks",
+    "huffdecode_lane_slots",
     "launches.bitpack",
     "launches.plane_producer",
     "launches.plane_consumer",

@@ -91,6 +91,121 @@ def test_kernel_matches_decode_many(chunk_bytes, n):
         assert 0 <= slack < 8
 
 
+# (plane, symbols) per chunk, as fractions of the chunk size; planes 0 and 1
+# have different tables, so a group of lanes gathers from both LUT rows.
+LANE_CASES = {
+    "idle-lanes": [(0, 1.0)] * 5,                   # 5 chunks: 3 idle lanes
+    "short-last": [(0, 1.0)] * 3 + [(0, 0.3)],      # short lane beside full ones
+    "mixed-planes": [(0, 1.0), (1, 1.0), (0, 0.6), (1, 1.0), (1, 0.1), (0, 1.0)],
+    "fewer-than-cap": [(1, 1.0), (0, 0.7)],
+    "one-chunk": [(0, 0.5)],
+}
+
+
+def _lane_chunks(case, chunk_bytes):
+    """Encoded chunks of ``LANE_CASES[case]``: payloads, counts, plane ids,
+    the host decoder's symbols and the stacked LUTs."""
+    tabs = [_table_for(_skewed_plane(1 << 14, seed=11)),
+            _table_for((np.arange(1 << 12) % 5).astype(np.uint8))]
+    payloads, counts, pids, want = [], [], [], []
+    for k, (p, frac) in enumerate(LANE_CASES[case]):
+        n = max(1, int(frac * chunk_bytes))
+        plane = (_skewed_plane(n, seed=k) if p == 0
+                 else (np.arange(n) * 7 % 5).astype(np.uint8))
+        lens = tabs[p]
+        pay = huffman.encode_chunks(plane, np.asarray([n]), lens,
+                                    huffman.canonical_codes(lens))
+        payloads += pay
+        counts.append(n)
+        pids.append(p)
+        want += huffman.decode_many(pay, [n], lens)
+    max_l = max(int(t.max(initial=1)) for t in tabs)
+    luts = np.zeros((len(tabs), 1 << max_l), dtype=np.int32)
+    for p, lens in enumerate(tabs):
+        ls, ll = huffman._build_lut(lens, huffman.canonical_codes(lens), max_l)
+        luts[p] = (ls.astype(np.int32) << 8) | ll.astype(np.int32)
+    return payloads, np.asarray(counts), np.asarray(pids), want, luts
+
+
+def _run_lanes(payloads, counts, pids, luts, chunk_bytes):
+    import jax.numpy as jnp
+
+    from repro.kernels import huffdecode
+
+    syms, cursors = huffdecode.huffdecode_chunks_multi(
+        jnp.asarray(_pack_words(payloads, chunk_bytes)),
+        jnp.asarray(pids, dtype=jnp.int32),
+        jnp.asarray(counts, dtype=jnp.int32),
+        jnp.asarray(luts),
+        chunk_bytes=chunk_bytes,
+    )
+    return np.asarray(syms), np.asarray(cursors, dtype=np.int64)
+
+
+def _cursor_ok(payload, cursor) -> bool:
+    try:
+        device_entropy._check_cursors(
+            [(0, 0)], [[payload]], np.asarray([len(payload)]), np.asarray([cursor])
+        )
+    except ValueError:
+        return False
+    return True
+
+
+@pytest.mark.parametrize("chunk_bytes", [4096, 16384])   # one / four output blocks
+@pytest.mark.parametrize("case", sorted(LANE_CASES))
+def test_kernel_lanes_match_decode_many(case, chunk_bytes):
+    """Interleaved lanes decode every chunk of a group exactly: idle lanes
+    past the last chunk, short lanes beside full ones, lanes of different
+    planes in one group, and launches of fewer chunks than the lane cap."""
+    from repro.kernels import huffdecode
+
+    payloads, counts, pids, want, luts = _lane_chunks(case, chunk_bytes)
+    g = huffdecode.lanes(len(counts), chunk_bytes, luts.size)
+    assert g == min(huffdecode.MAX_LANES, len(counts))
+    syms, cursors = _run_lanes(payloads, counts, pids, luts, chunk_bytes)
+    assert syms.shape == (len(counts), chunk_bytes)
+    for k, w in enumerate(want):
+        assert np.array_equal(syms[k, : counts[k]], w), k
+        assert _cursor_ok(payloads[k], cursors[k]), k
+
+
+@pytest.mark.parametrize("damage", ["truncated", "flipped"])
+def test_kernel_corrupt_lane_leaves_neighbours_exact(damage):
+    """A corrupt chunk in one lane: the other lanes of its group decode
+    exactly and keep valid cursors, while its own cursor fails the host
+    check."""
+    cb = 4096
+    payloads, counts, pids, want, luts = _lane_chunks("mixed-planes", cb)
+    bad = 2                                          # a short plane-0 lane
+    if damage == "truncated":
+        payloads[bad] = payloads[bad][: len(payloads[bad]) // 2]
+    else:
+        flip = bytearray(payloads[bad])
+        flip[len(flip) // 3] ^= 0xA5
+        payloads[bad] = bytes(flip)
+    syms, cursors = _run_lanes(payloads, counts, pids, luts, cb)
+    for k, w in enumerate(want):
+        if k == bad:
+            assert not _cursor_ok(payloads[k], cursors[k])
+            continue
+        assert np.array_equal(syms[k, : counts[k]], w), k
+        assert _cursor_ok(payloads[k], cursors[k]), k
+
+
+def test_kernel_count_past_capacity_stays_in_its_row():
+    """A count larger than the chunk (corrupt metadata, which the driver
+    refuses before a launch) decodes no further than the chunk's row: the
+    next lane's symbols stay exact."""
+    cb = 4096
+    payloads, counts, pids, want, luts = _lane_chunks("mixed-planes", cb)
+    counts = counts.copy()
+    counts[0] = 3 * cb
+    syms, _ = _run_lanes(payloads, counts, pids, luts, cb)
+    for k, w in enumerate(want):
+        assert np.array_equal(syms[k, : len(w)], w), k
+
+
 def test_kernel_multi_table_selection():
     """Chunks of different planes gather against their own LUT row at the
     shared stacked width."""
@@ -299,6 +414,13 @@ def test_decode_planes_corruption_rejected():
                 [[e0] + entries[0][1:]], [[dirty] + payloads[0][1:]],
                 tables, params,
             )
+
+    # a HUFF chunk longer than the chunk size → refused before the launch
+    e0 = dataclasses.replace(entries[0][0], raw_len=cb + 4)
+    with pytest.raises(ValueError, match="longer than chunk_bytes"):
+        device_entropy.decode_planes(
+            [[e0] + entries[0][1:]], [payloads[0]], tables, params
+        )
 
     # missing table → same corrupt-stream error as the host codec
     with pytest.raises(IOError, match="no plane table"):

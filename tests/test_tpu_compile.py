@@ -6,7 +6,8 @@ not whole tiles, and fast-memory overruns pass every interpret-mode test and
 are refused only here.  Each test lowers one kernel for a *described*
 v5e (no chip attached; nothing runs) at a 4096 x 14336 leaf, the default
 plane chunks (131,072 bytes for bf16, 65,536 for fp32) and 8 chunks per
-dispatch, and checks that the program holds a Mosaic kernel.
+dispatch, and checks that the program holds a Mosaic kernel.  The Huffman decoder also
+compiles at the lane count it picks for each launch shape.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library, and every test
@@ -113,10 +114,9 @@ def test_bitpack_compiles(one_chip):
     )
 
 
-@pytest.mark.parametrize("itemsize, lut_bits", [(2, 9), (2, 15), (4, 15)])
-def test_huffdecode_compiles(one_chip, itemsize, lut_bits):
-    """A narrow LUT, and the widest (MAXL-bit codes) for every plane of a
-    bf16 / fp32 leaf: the largest SMEM footprint the decoder can ask for."""
+def _compile_huffdecode(one_chip, itemsize, lut_bits, chunks):
+    """Compile one decode launch of ``chunks`` chunks against ``itemsize``
+    LUT rows; return the lane count it runs."""
     cb = CHUNK_BYTES[itemsize]
 
     def decode(words, pids, counts, luts):
@@ -126,11 +126,35 @@ def test_huffdecode_compiles(one_chip, itemsize, lut_bits):
 
     _compile(
         decode,
-        jax.ShapeDtypeStruct((DISPATCH_CHUNKS * cb // 4,), jnp.uint32, sharding=one_chip),
-        jax.ShapeDtypeStruct((DISPATCH_CHUNKS,), jnp.int32, sharding=one_chip),
-        jax.ShapeDtypeStruct((DISPATCH_CHUNKS,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((chunks * cb // 4,), jnp.uint32, sharding=one_chip),
+        jax.ShapeDtypeStruct((chunks,), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((chunks,), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((itemsize, 1 << lut_bits), jnp.int32, sharding=one_chip),
     )
+    return huffdecode.lanes(chunks, cb, itemsize << lut_bits)
+
+
+@pytest.mark.parametrize("itemsize, lut_bits", [(2, 9), (2, 15), (4, 15)])
+def test_huffdecode_compiles(one_chip, itemsize, lut_bits):
+    """A narrow LUT, and the widest (MAXL-bit codes) for every plane of a
+    bf16 / fp32 leaf: the largest SMEM footprint the decoder can ask for,
+    at the lane count it picks for 8 chunks a dispatch."""
+    g = _compile_huffdecode(one_chip, itemsize, lut_bits, DISPATCH_CHUNKS)
+    assert g == huffdecode.MAX_LANES
+
+
+@pytest.mark.parametrize(
+    "itemsize, chunks, lanes",
+    [(2, 7, huffdecode.MAX_LANES), (4, 7, huffdecode.MAX_LANES), (2, 1, 1),
+     (2, 1024, huffdecode.MAX_LANES)],
+    ids=["bf16-ragged", "fp32-ragged", "one-chunk", "bf16-widest-launch"],
+)
+def test_huffdecode_lanes_compile(one_chip, itemsize, chunks, lanes):
+    """The widest LUTs at a chunk count that is not a multiple of the lane
+    count (an idle lane in the last step), a one-chunk launch (one lane),
+    and the most chunks one launch window holds (their ids, counts and
+    cursors share the scalar memory)."""
+    assert _compile_huffdecode(one_chip, itemsize, 15, chunks) == lanes
 
 
 @pytest.mark.parametrize("itemsize", [2, 4], ids=["bf16", "fp32"])

@@ -212,6 +212,60 @@ def test_restore_counts_one_fetch_per_launch(tmp_path, fresh, manager):
     assert traced["compiles"] == 0
 
 
+def _huff_stream(n_elems: int):
+    """A bf16 stream of Qwen-like weights: HUFF exponent chunks (the raw
+    sign/mantissa plane stays out of the kernel), parsed for the decoders."""
+    rng = np.random.default_rng(n_elems)
+    arr = (rng.standard_normal(n_elems) * 0.02).astype(ml_dtypes.bfloat16)
+    meta, mv = container.unpack_stream(zipnn.compress_array(arr, HUFF).blob)
+    payloads = [
+        [container.payload_view(meta, mv, p, c) for c in range(len(meta.entries[p]))]
+        for p in range(meta.n_planes)
+    ]
+    params = codec.CodecParams(chunk_bytes=meta.chunk_bytes, backend="huffman")
+    n_huff = sum(e.method == codec.Method.HUFF for pe in meta.entries for e in pe)
+    return meta, payloads, params, n_huff
+
+
+@pytest.mark.parametrize("path", ["decode_planes", "feed"])
+@pytest.mark.parametrize("chunks, aligned", [(8, True), (6.5, False)],
+                         ids=["group-aligned", "ragged"])
+def test_huffdecode_lane_counters(fresh, path, chunks, aligned):
+    """``huffdecode_chunks`` counts the chunks a decode launched and
+    ``huffdecode_lane_slots`` the lanes that ran them: a launch whose chunk
+    count is a multiple of its lane count fills every lane."""
+    from repro.kernels import huffdecode
+
+    cb = HUFF.chunk_param_bytes // 2
+    meta, payloads, params, n_huff = _huff_stream(int(chunks * cb))
+    assert n_huff == -(-int(chunks * cb) // cb)          # the exponent plane only
+    luts, _ = device_entropy._stacked_luts(meta.tables)
+    g = huffdecode.lanes(n_huff, meta.chunk_bytes, luts.size)
+    assert g > 1 and (n_huff % g == 0) == aligned
+
+    def decode():
+        if path == "decode_planes":
+            return device_entropy.decode_planes(
+                meta.entries, payloads, meta.tables, params, device_resident=True
+            )
+        return feed.decode()
+
+    if path == "feed":
+        c0 = tracing.counters()
+        feed = device_entropy.PayloadFeed(meta.entries, payloads, meta.tables, params)
+        built = tracing.counters()
+        assert built["huffdecode_chunks"] - c0["huffdecode_chunks"] == n_huff
+    c0 = tracing.counters()
+    jax.block_until_ready(decode())
+    c1 = tracing.counters()
+    chunks_n = c1["huffdecode_chunks"] - c0["huffdecode_chunks"]
+    slots = c1["huffdecode_lane_slots"] - c0["huffdecode_lane_slots"]
+    assert c1["launches.huffdecode"] - c0["launches.huffdecode"] == 1
+    assert chunks_n == n_huff
+    assert slots == g * -(-n_huff // g)
+    assert (chunks_n / slots == 1.0) == aligned
+
+
 def test_transfer_stats_is_a_view_of_the_payload_counters(fresh):
     tracing.count_payload_upload(100)
     tracing.count("d2h_fetches", 3)
